@@ -119,7 +119,7 @@ def fit_timing_constants(spec=None, quiet: bool = False) -> dict:
     are recorded as ``hwspec.HOST_FIT``.
     """
     from repro.core.driver import Dram
-    from repro.kernels._compat import resolve_interpret
+    from repro.kernels._platform import resolve_interpret
     from repro.kernels.vta_gemm.kernel import vta_gemm_pallas
 
     spec = spec or hwspec.pynq()

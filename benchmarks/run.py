@@ -1,9 +1,9 @@
 """Benchmark entry point — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV summary lines plus each
-benchmark's own table.  The dry-run roofline table is included when
-experiments/dryrun JSONs exist (produced by `python -m
-repro.launch.dryrun --all`).
+benchmark's own table.  The times are the host clock of whatever
+platform JAX runs on (the Pallas interpreter on a CPU), not chip
+measurements.
 """
 from __future__ import annotations
 
@@ -17,7 +17,10 @@ def _section(title):
 def main() -> None:
     from benchmarks import (bench_fig15_roofline, bench_fig16_e2e,
                             bench_kernels, bench_program,
-                            bench_roofline_table, bench_sec26_bandwidth)
+                            bench_sec26_bandwidth)
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     summary = []
 
@@ -108,15 +111,6 @@ def main() -> None:
                     " ".join(f"x{w['speedup_measured']:.2f}"
                              for w in arow["workloads"]) +
                     " (deep: python -m benchmarks.bench_program)"))
-
-    _section("Dry-run roofline table (from experiments/dryrun)")
-    t0 = time.perf_counter()
-    try:
-        rs = bench_roofline_table.run()
-        summary.append(("roofline_table", (time.perf_counter() - t0) * 1e6,
-                        f"{len(rs)} cells"))
-    except Exception as e:
-        print(f"(no dry-run results yet: {e})")
 
     _section("summary CSV")
     print("name,us_per_call,derived")

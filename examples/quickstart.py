@@ -51,7 +51,7 @@
 13. Shrink the weights below a byte: the same linear layer at bits=4
    stores its weight constant int4-PACKED in the DRAM image (half the
    staged bytes — describe() shows it), both engines decode the packed
-   stream bit-exactly, decode-shaped calls auto-route to the T-MAC-style
+   stream bit-exactly, decode-shaped calls auto-route to the bit-plane
    LUT-GEMM kernel, and the int4 output tracks the int8 path's dequant
    reference within the coarser quantization step.
 14. Kill a serving slot mid-dialogue and watch the pool heal itself:
@@ -73,6 +73,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import Program, hwspec, quantize as q
 from repro.core.backend import CrossBackendChecker, assert_fast_path
 from repro.core.conv import ConvShape, conv2d_reference
@@ -83,6 +84,7 @@ from repro.core.simulator import TimingModel
 
 
 def main() -> None:
+    compile_cache.enable()
     spec = hwspec.pynq()
     print(f"VTA template: {spec.batch}x{spec.block_in}x{spec.block_out} "
           f"GEMM core @ {spec.freq_mhz:.0f} MHz "

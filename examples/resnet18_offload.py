@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import Program, hwspec, quantize as q
 from repro.core.backend import assert_fast_path
 from repro.core.conv import ConvShape, conv2d_reference, read_conv_result, \
@@ -127,6 +128,7 @@ def heterogeneous_chain(name: str) -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     name = sys.argv[1] if len(sys.argv) > 1 else "C9"
     per_layer_study(name)
     heterogeneous_chain(name)

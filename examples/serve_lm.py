@@ -26,6 +26,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.serve import DevicePool
 from repro.models.vta_decoder import DecoderConfig, QuantDecoder
 
@@ -43,6 +44,7 @@ def greedy_decode_reference(dec: QuantDecoder, prompt_tok: int,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=4)
     ap.add_argument("--steps", type=int, default=24)

@@ -9,11 +9,13 @@ Run:  PYTHONPATH=src python examples/train_lm.py --arch olmo-1b --steps 300
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs import get_arch, reduced
 from repro.launch.train import Trainer
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--steps", type=int, default=300)

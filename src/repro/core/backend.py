@@ -197,8 +197,10 @@ class PallasBackend:
     name = "pallas"
 
     #: auto LUT selection: per-tile activation rows at or below this are
-    #: "decode-shaped" (weight traffic dominates; the table transform is
-    #: cheap) and route to the LUT-GEMM kernel when weights are sub-byte
+    #: "decode-shaped" and route to the LUT-GEMM (bit-plane) kernel when
+    #: weights are sub-byte.  Fitted to the interpreter; on the MXU that
+    #: kernel costs one int8 pass per weight bit, so the value awaits a
+    #: chip measurement
     LUT_MAX_ROWS = 16
 
     def __init__(self, interpret: Optional[bool] = None,
@@ -207,7 +209,7 @@ class PallasBackend:
                  batch_tiles: bool = True,
                  cache_decode: bool = True,
                  use_lut: Optional[bool] = None):
-        # interpret=None -> auto (native on TPU, interpreter elsewhere)
+        # interpret=None follows the platform (see resolved_interpret)
         self.interpret = interpret
         self.check_tokens = check_tokens
         self.coalesce_subgrids = coalesce_subgrids
@@ -218,10 +220,19 @@ class PallasBackend:
         # the dense kernel (A/B baseline).  int8 specs never use it.
         self.use_lut = use_lut
 
+    @property
+    def resolved_interpret(self) -> bool:
+        """Whether the kernels this engine launches run in the Pallas
+        interpreter (True) or Mosaic-compiled on the TPU (False): the
+        constructor's `interpret`, with None resolved from the platform."""
+        from ..kernels._platform import resolve_interpret
+        return resolve_interpret(self.interpret)
+
     def _lut_select(self, spec: HardwareSpec, rows: int) -> bool:
-        """Per-shape kernel choice for one GEMM launch group: T-MAC LUT
-        lookup vs dense MXU GEMM.  Both are bit-exact; this is purely a
-        roofline call, so the fuzzer sweeps it freely."""
+        """Per-shape kernel choice for one GEMM launch group: LUT-GEMM
+        (one MXU pass per weight bit plane) vs dense MXU GEMM.  Both are
+        bit-exact; this is purely a roofline call, so the fuzzer sweeps it
+        freely."""
         if not spec.wgt_packed or self.use_lut is False:
             return False
         return bool(self.use_lut) or rows <= self.LUT_MAX_ROWS
@@ -853,10 +864,9 @@ class PallasBackend:
         import jax
         import jax.numpy as jnp
 
-        from ..kernels._compat import resolve_interpret
         from ..kernels.lut_gemm.kernel import lut_gemm_pallas
         from ..kernels.vta_gemm.kernel import vta_gemm_pallas
-        interpret = resolve_interpret(self.interpret)
+        interpret = self.resolved_interpret
 
         T = len(tiles)
         wgroups0, shift = plans[0]
@@ -881,7 +891,7 @@ class PallasBackend:
             if shift is not None:
                 kw.update(epilogue="requant", shift=shift)
             # per-shape kernel choice: sub-byte weights on decode-shaped
-            # tiles go through the T-MAC LUT kernel (same operands, same
+            # tiles go through the LUT-GEMM kernel (same operands, same
             # epilogue contract, bit-identical output)
             use_lut = self._lut_select(spec, Rg)
 

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .meshctx import shard_map
-
 Params = Any
 
 
@@ -45,9 +43,7 @@ def compressed_mean_local(g: jax.Array, err: jax.Array, axes
     names = axes if isinstance(axes, tuple) else (axes,)
     n = 1
     for a in names:
-        # jax.lax.axis_size is jax >= 0.5; psum(1, axis) works everywhere
-        size_of = getattr(jax.lax, "axis_size", None)
-        n = n * (size_of(a) if size_of is not None else jax.lax.psum(1, a))
+        n = n * jax.lax.axis_size(a)
     gi = g.astype(jnp.float32) + err
     amax = jax.lax.pmax(jnp.max(jnp.abs(gi)), names)    # shared scale
     scale = jnp.maximum(amax, 1e-12) / 127.0
@@ -68,7 +64,7 @@ def compressed_mean(stacked_grads: jax.Array, errors: jax.Array,
         out, err = compressed_mean_local(g[0], e[0], axis)
         return out[None], err[None]
 
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(axis), P(axis)),
                        out_specs=(P(axis), P(axis)))
     mean_stacked, new_err = fn(stacked_grads, errors)

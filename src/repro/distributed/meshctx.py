@@ -37,19 +37,6 @@ def use_mesh(mesh: Mesh):
         _MESH = prev
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map: `jax.shard_map` only exists on jax >= 0.5;
-    0.4.x ships the same API under jax.experimental.shard_map, where the
-    replication-checker flag is named check_rep instead of check_vma."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
 def _axis_size(mesh: Mesh, entry) -> int:
     if entry is None:
         return 1

@@ -2,8 +2,10 @@
 
 Each kernel ships three files: kernel.py (pl.pallas_call + explicit
 BlockSpec VMEM tiling), ops.py (jit'd public wrapper with pallas/oracle
-dispatch), ref.py (pure-jnp oracle).  All kernels validate in
-interpret=True mode on CPU; TPU is the compilation target.
+dispatch), ref.py (pure-jnp oracle).  ``interpret=None`` (the default
+everywhere) follows the platform — see ``_platform.resolve_interpret``:
+the CPU test suite validates in the Pallas interpreter, a TPU compiles
+through Mosaic.
 """
 from . import (decode_attention, flash_attention, gla_chunk,  # noqa: F401
                tensor_alu, vta_gemm)

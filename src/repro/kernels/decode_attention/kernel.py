@@ -17,13 +17,14 @@ scratch m/l: (G, 1), acc: (G, D).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .._platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -66,7 +67,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             kv_len: jax.Array, *, bk: int = 512,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: Optional[bool] = None) -> jax.Array:
     """q: (B*KH, G, D) one new token per sequence, grouped per kv head;
     k/v: (B*KH, S, D) cache (padded to S); kv_len: (1,) int32 valid length.
     """
@@ -93,7 +94,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kv_len.astype(jnp.int32).reshape(1), q, k, v)

@@ -1,6 +1,8 @@
 """Public op: batched GQA decode step over a (possibly padded) KV cache."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -12,7 +14,8 @@ from .ref import decode_attention_ref, decode_attention_ref_4d
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      kv_len: jax.Array, *, use_pallas: bool = False,
-                     interpret: bool = True, bk: int = 512) -> jax.Array:
+                     interpret: Optional[bool] = None,
+                     bk: int = 512) -> jax.Array:
     """q: (B, 1, HQ, D); caches: (B, S, KH, D); kv_len: scalar int32.
     Returns (B, 1, HQ, D)."""
     B, _, HQ, D = q.shape

@@ -18,13 +18,14 @@ VMEM working set per step (bq=bk=256, D=128, f32):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .._platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -76,7 +77,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            group: int, causal: bool = True,
                            bq: int = 256, bk: int = 256,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q: (B*HQ, S, D);  k/v: (B*KH, S, D);  group = HQ // KH.
 
     The kv index_map sends q head h to kv head h // group — GQA without
@@ -107,7 +108,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

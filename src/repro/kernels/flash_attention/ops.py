@@ -1,6 +1,8 @@
 """Public op: (B, S, H, D)-layout GQA attention with pallas/ref dispatch."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -33,7 +35,7 @@ def _from_heads(x: jax.Array, B: int) -> jax.Array:
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, use_pallas: bool = False,
-                    interpret: bool = True, bq: int = 256,
+                    interpret: Optional[bool] = None, bq: int = 256,
                     bk: int = 256) -> jax.Array:
     """q: (B, S, HQ, D); k/v: (B, S, KH, D). Returns (B, S, HQ, D)."""
     B, S, HQ, D = q.shape

@@ -18,13 +18,14 @@ Math per chunk (L = within-chunk cumsum of log-decay):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .._platform import resolve_interpret
 
 
 def _gla_kernel(q_ref, k_ref, v_ref, la_ref, h0_ref, y_ref, hout_ref,
@@ -72,7 +73,7 @@ def _gla_kernel(q_ref, k_ref, v_ref, la_ref, h0_ref, y_ref, hout_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gla_chunk_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                      la: jax.Array, h0: jax.Array, *,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """q,k: (BH, nc, Q, N); v: (BH, nc, Q, P); la: (BH, nc, Q);
     h0: (BH, N, P) f32.  Returns (y: (BH, nc, Q, P), h: (BH, N, P))."""
     BH, nc, Q, N = q.shape
@@ -96,7 +97,7 @@ def gla_chunk_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((BH, N, P_), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P_), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, la, h0)

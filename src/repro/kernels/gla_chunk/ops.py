@@ -12,7 +12,8 @@ from .ref import gla_chunk_ref
 
 def gla_chunk(q: jax.Array, k: jax.Array, v: jax.Array, la: jax.Array,
               h0: Optional[jax.Array] = None, *, chunk: int = 64,
-              use_pallas: bool = False, interpret: bool = True
+              use_pallas: bool = False,
+              interpret: Optional[bool] = None
               ) -> Tuple[jax.Array, jax.Array]:
     """q,k: (B, S, H, N); v: (B, S, H, P); la: (B, S, H) log-decay;
     h0: (B, H, N, P) or None.  Returns (y (B,S,H,P), h (B,H,N,P))."""

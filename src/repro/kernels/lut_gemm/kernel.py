@@ -1,70 +1,54 @@
-"""T-MAC-style LUT-GEMM for sub-byte weights (Pallas).
+"""Sub-byte weight GEMM by bit-plane decomposition (Pallas).
 
-For memory-bound decode matmuls (few activation rows, large sub-byte
-weight matrix) the MXU is idle waiting on weight bytes; the T-MAC trick
-replaces the multiply array with table lookups over precomputed partial
-sums of the activations:
+The T-MAC view of a sub-byte GEMM: decompose each b-bit two's-complement
+weight into its bit planes,
 
-  * Split the reduction axis K into G = K/g groups of g lanes.
-  * Per activation row m and group, precompute the table of all 2^g
-    subset sums  T[m, grp, p] = sum_{j: bit j of p} a[m, grp*g + j]
-    — one small (g x 2^g) integer matmul against the bit-pattern matrix.
-  * Decompose each b-bit two's-complement weight into its bit planes:
-    w = sum_{t<b-1} 2^t * bit_t - 2^(b-1) * bit_{b-1}.  Per plane and
-    group, the g weight bits along the reduction lanes form a g-bit
-    table index  idx_t[grp, n].
-  * The GEMM becomes gathers + adds:
-      acc[m, n] = sum_t coef_t * sum_grp T[m, grp, idx_t[grp, n]]
+    w = sum_{t<b-1} 2^t * bit_t  -  2^(b-1) * bit_{b-1},
+
+so the GEMM becomes b GEMMs against 0/1 matrices, recombined with the
+plane coefficients:
+
+    acc[m, n] = sum_t coef_t * sum_k a[m, k] * bit_t[k, n].
+
+T-MAC evaluates the inner sum by looking up precomputed subset sums of
+the activations (a table indexed by g weight bits at a time).  That
+lookup is a 3-D dynamic gather, which Mosaic does not lower ("Only 2D
+gather is supported"); the same sum is a contraction of the activations
+against the 0/1 plane, which the MXU runs natively as int8 x int8 ->
+int32.  The kernel therefore issues one MXU pass per bit plane over the
+weight column block it holds in VMEM.
 
 All arithmetic is exact int32, so the result is BIT-IDENTICAL to the
 dense int8 GEMM over the sign-extended weights — the property the
 cross-backend fuzzer locks in.  The fused requant epilogue reproduces
 ``vta_gemm``'s exactly (truncating arithmetic shift, clip, int8).
-
-The gathers use ``jnp.take_along_axis``; on interpret mode (CPU, the
-validation target) this lowers directly.  Native-TPU Mosaic restricts
-dynamic gathers — a one-hot-contraction fallback is the known rewrite if
-a native pass lands (see ROADMAP "native-TPU pass").
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .._platform import resolve_interpret
 
 
-def _lut_kernel(a_ref, w_ref, o_ref, *, bits: int, group: int,
-                epilogue: str, shift: int):
-    a = a_ref[...].astype(jnp.int32)          # (M, K)
-    w = w_ref[...].astype(jnp.int32)          # (K, bn)
-    M, K = a.shape
-    N = w.shape[1]
-    G = K // group
-    P = 1 << group
-
-    # activation table: one (g x 2^g) subset-sum matmul per row/group
-    pats = jnp.arange(P, dtype=jnp.int32)
-    bitsel = ((pats[:, None] >> jnp.arange(group)[None, :]) & 1)  # (P, g)
-    ag = a.reshape(M, G, group)
-    table = jax.lax.dot_general(
-        ag, bitsel.astype(jnp.int32).T,
-        (((2,), (0,)), ((), ())), preferred_element_type=jnp.int32)  # (M,G,P)
-
-    # weight bit planes -> g-bit table indices per (plane, group, n)
-    wu = (w & ((1 << bits) - 1)).reshape(G, group, N)
-    lane_w = (jnp.int32(1) << jnp.arange(group, dtype=jnp.int32))
-    acc = jnp.zeros((M, N), jnp.int32)
+def _lut_kernel(a_ref, w_ref, o_ref, *, bits: int, epilogue: str,
+                shift: int):
+    a = a_ref[...]                            # (M, K) int8
+    w = w_ref[...].astype(jnp.int32)          # (K, bn) sign-extended b-bit
+    acc = None
     for t in range(bits):
-        bit = (wu >> t) & 1
-        idx = jnp.sum(bit * lane_w[None, :, None], axis=1)           # (G, N)
-        picked = jnp.take_along_axis(
-            table, jnp.broadcast_to(idx[None], (M, G, N)), axis=2)
+        plane = ((w >> t) & 1).astype(jnp.int8)
+        part = jax.lax.dot_general(
+            a, plane, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
         coef = -(1 << t) if t == bits - 1 else (1 << t)   # MSB = sign plane
-        acc = acc + jnp.int32(coef) * jnp.sum(picked, axis=1)
+        part = part * jnp.int32(coef)
+        acc = part if acc is None else acc + part
 
     if epilogue == "none":
         o_ref[...] = acc
@@ -77,30 +61,30 @@ def _lut_kernel(a_ref, w_ref, o_ref, *, bits: int, group: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bits", "group", "epilogue", "shift", "bn", "interpret"))
+    static_argnames=("bits", "epilogue", "shift", "bn", "interpret"))
 def lut_gemm_pallas(a: jax.Array, w: jax.Array, *, bits: int,
-                    group: int = 4, epilogue: str = "none", shift: int = 0,
-                    bn: int = 128, interpret: bool = True) -> jax.Array:
-    """C[M,N] = epilogue(A[M,K](int8) @ W[K,N](int{bits})) via table lookup.
+                    epilogue: str = "none", shift: int = 0,
+                    bn: int = 128,
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """C[M,N] = epilogue(A[M,K](int8) @ W[K,N](int{bits})) by bit planes.
 
     Same operand/epilogue contract as ``vta_gemm_pallas`` (so the backend
     can swap it in per shape), minus bias/dequant which the decode path
     never fuses.  `w` values must lie in the b-bit two's-complement range
-    (they are the sign-extended int8 the WGT SRAM holds); K must be a
-    multiple of `group`, N of `bn`.
+    (they are the sign-extended int8 the WGT SRAM holds); N must be a
+    multiple of `bn`.  `interpret` resolves from the platform when None.
     """
     if bits not in (1, 2, 4):
         raise ValueError(f"lut_gemm: bits must be 1, 2 or 4, got {bits}")
     M, K = a.shape
     K2, N = w.shape
     assert K == K2, (a.shape, w.shape)
-    assert K % group == 0, f"pad K to a multiple of group: {K} vs {group}"
     assert N % bn == 0, f"pad N to a multiple of bn: {N} vs {bn}"
     out_dtype = {"none": jnp.int32, "requant": jnp.int8}[epilogue]
 
     return pl.pallas_call(
-        functools.partial(_lut_kernel, bits=bits, group=group,
-                          epilogue=epilogue, shift=shift),
+        functools.partial(_lut_kernel, bits=bits, epilogue=epilogue,
+                          shift=shift),
         grid=(N // bn,),
         in_specs=[
             pl.BlockSpec((M, K), lambda j: (0, 0)),   # activations (small M)
@@ -108,6 +92,7 @@ def lut_gemm_pallas(a: jax.Array, w: jax.Array, *, bits: int,
         ],
         out_specs=pl.BlockSpec((M, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=resolve_interpret(interpret),
     )(a, w)

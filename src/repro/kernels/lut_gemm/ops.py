@@ -1,9 +1,9 @@
-"""Public op: sub-byte weight GEMM by activation-table lookup (T-MAC).
+"""Public op: sub-byte weight GEMM by bit-plane decomposition.
 
 Dispatches to the Pallas kernel or the jnp oracle; both share exact
-integer semantics.  Pads K to a group multiple and N to the column block
-(zero weight values contribute nothing on any bit plane, zero activation
-lanes add nothing to any subset sum — padding is exact).
+integer semantics.  Pads K to the 128-lane width and N to the column
+block (zero weight values contribute nothing on any bit plane, zero
+activation lanes add nothing to any sum — padding is exact).
 """
 from __future__ import annotations
 
@@ -12,9 +12,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .._compat import resolve_interpret
 from .kernel import lut_gemm_pallas
 from .ref import lut_gemm_ref
+
+_LANES = 128
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -26,7 +27,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def lut_gemm(a: jax.Array, w: jax.Array, *, bits: int, group: int = 4,
+def lut_gemm(a: jax.Array, w: jax.Array, *, bits: int,
              epilogue: str = "none", shift: int = 0,
              use_pallas: bool = False, interpret: Optional[bool] = None,
              bn: int = 128) -> jax.Array:
@@ -40,9 +41,8 @@ def lut_gemm(a: jax.Array, w: jax.Array, *, bits: int, group: int = 4,
     _, N = w.shape
     if not use_pallas:
         return lut_gemm_ref(a, w, epilogue=epilogue, shift=shift)
-    ap = _pad_to(a, 1, group)
-    wp = _pad_to(_pad_to(w, 0, group), 1, bn)
-    out = lut_gemm_pallas(ap, wp, bits=bits, group=group,
-                          epilogue=epilogue, shift=shift, bn=bn,
-                          interpret=resolve_interpret(interpret))
+    ap = _pad_to(a, 1, _LANES)
+    wp = _pad_to(_pad_to(w, 0, _LANES), 1, bn)
+    out = lut_gemm_pallas(ap, wp, bits=bits, epilogue=epilogue, shift=shift,
+                          bn=bn, interpret=interpret)
     return out[:M, :N]

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .._platform import resolve_interpret
+
 ALU_OPS = ("min", "max", "add", "shr", "mul")
 
 
@@ -46,24 +48,32 @@ def _alu_kernel(dst_ref, src_ref, o_ref, *, chain: Tuple[Tuple[str, Optional[int
     o_ref[...] = x
 
 
-@functools.partial(jax.jit, static_argnames=("chain", "bm", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("chain", "bm", "bn", "interpret"))
 def tensor_alu_pallas(dst: jax.Array, src: Optional[jax.Array] = None,
                       *, chain: Tuple[Tuple[str, Optional[int]], ...],
-                      bm: int = 256, interpret: bool = True) -> jax.Array:
+                      bm: int = 256, bn: int = 512,
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Apply a chain of VTA ALU ops to an int32 tensor.
 
     chain: tuple of (op, imm) — imm=None means tensor-tensor with `src`.
-    dst/src: (M, N) int32 with N a multiple of 128 (lane width).
+    dst/src: (M, N) int32, blocked (bm, bn) over both axes; M must divide
+    into bm-row blocks and N into bn-column blocks (bn a multiple of the
+    128-lane width).  At the default (256, 512) one block is 512 KiB, so
+    dst, src and out double-buffered take 3 MiB of the 16 MiB scoped VMEM
+    whatever the tensor's size.
     """
     M, N = dst.shape
     bm = min(bm, M)
-    assert M % bm == 0, (M, bm)
+    bn = min(bn, N)
+    assert M % bm == 0 and N % bn == 0, ((M, N), (bm, bn))
     has_src = any(imm is None for _, imm in chain)
-    in_specs = [pl.BlockSpec((bm, N), lambda i: (i, 0))]
+    block = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
+    in_specs = [block]
     args = [dst]
     if has_src:
         assert src is not None
-        in_specs.append(pl.BlockSpec((bm, N), lambda i: (i, 0)))
+        in_specs.append(block)
         args.append(src)
 
     def kernel(*refs):
@@ -75,9 +85,11 @@ def tensor_alu_pallas(dst: jax.Array, src: Optional[jax.Array] = None,
 
     return pl.pallas_call(
         kernel,
-        grid=(M // bm,),
+        grid=(M // bm, N // bn),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
+        out_specs=block,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=resolve_interpret(interpret),
     )(*args)

@@ -6,11 +6,21 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .._compat import resolve_interpret
 from .kernel import tensor_alu_pallas
 from .ref import tensor_alu_ref
 
 _LANES = 128  # VPU lane width: last dim of a native tile
+_MAX_COL_BLOCK = 512  # (256, 512) int32 blocks: 3 MiB of VMEM double-buffered
+
+
+def _col_block(n: int) -> int:
+    """Widest lane-multiple column block, at most _MAX_COL_BLOCK, that
+    divides the lane-aligned width `n` (no padding beyond the lane
+    round-up)."""
+    lanes = n // _LANES
+    k = max(d for d in range(1, _MAX_COL_BLOCK // _LANES + 1)
+            if lanes % d == 0)
+    return k * _LANES
 
 
 def tensor_alu(dst: jax.Array, src: Optional[jax.Array] = None,
@@ -32,8 +42,9 @@ def tensor_alu(dst: jax.Array, src: Optional[jax.Array] = None,
         dst = jnp.pad(dst, widths)
         if src is not None:
             src = jnp.pad(src, widths)
-    out = tensor_alu_pallas(dst, src, chain=chain, bm=bm,
-                            interpret=resolve_interpret(interpret))
+    out = tensor_alu_pallas(dst, src, chain=chain, bm=bm_eff,
+                            bn=_col_block(N + pad_n),
+                            interpret=interpret)
     if pad_m or pad_n:
         out = out[:M, :N]
     return out

@@ -16,6 +16,9 @@ Semantics (faithful to the VTA datapath):
       "requant": out = clip((acc + bias) >> shift) as int8  (truncating SHR)
       "dequant": out = (acc + bias) * scale as float32      (LM serving path)
 
+The MXU is fed int8 x int8 with an int32 result type: Mosaic has no
+int32 x int32 matmul, so the operands are never widened before the dot.
+
 Block shapes default to (128, 128, 128): MXU-aligned (int8 min tile is
 (32,128); 128x128 keeps both matmul operands and the int32 accumulator at
 hardware-native tiling).  VMEM working set per grid step:
@@ -32,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .._platform import resolve_interpret
 
 
 def _gemm_kernel(a_ref, w_ref, bias_ref, scale_ref, o_ref, acc_ref, *,
@@ -43,10 +46,9 @@ def _gemm_kernel(a_ref, w_ref, bias_ref, scale_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
     acc_ref[...] += jax.lax.dot_general(
-        a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -74,16 +76,19 @@ def vta_gemm_pallas(a: jax.Array, w: jax.Array,
                     scale: Optional[jax.Array] = None,
                     *, epilogue: str = "none", shift: int = 0,
                     bm: int = 128, bn: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """C[M,N] = epilogue(A[M,K](int8) @ W[K,N](int8) + bias).
 
     bias: (N,) int32, scale: (N,) float32 (per-output-channel, like VTA's
-    per-filter requant constants).  `interpret=True` for CPU validation;
-    on TPU pass interpret=False.
+    per-filter requant constants).  `interpret` resolves from the
+    platform when None (see ``kernels._platform``).
     """
     M, K = a.shape
     K2, N = w.shape
     assert K == K2, (a.shape, w.shape)
+    if a.dtype != jnp.int8 or w.dtype != jnp.int8:
+        raise TypeError(f"vta_gemm takes int8 operands (the MXU's int8 "
+                        f"path), got {a.dtype} x {w.dtype}")
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, \
         f"pad shapes to block multiples: {(M, N, K)} vs {(bm, bn, bk)}"
     nk = K // bk
@@ -123,7 +128,7 @@ def vta_gemm_pallas(a: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],  # register file
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)

@@ -13,7 +13,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .._compat import resolve_interpret
 from .kernel import vta_gemm_pallas
 from .ref import vta_gemm_ref
 
@@ -39,7 +38,8 @@ def vta_gemm(a: jax.Array, w: jax.Array,
     a: (M, K) int8;  w: (K, N) int8;  bias: (N,) int32;  scale: (N,) f32.
     use_pallas=False runs the jnp oracle (identical math) — used by the
     dry-run so cost_analysis sees real FLOPs; tests exercise both paths.
-    interpret=None auto-selects (native on TPU, interpreter elsewhere).
+    interpret=None follows the platform (native on TPU, interpreter
+    elsewhere; see ``kernels._platform``).
     """
     if not use_pallas:
         return vta_gemm_ref(a, w, bias, scale, epilogue=epilogue, shift=shift)
@@ -51,7 +51,7 @@ def vta_gemm(a: jax.Array, w: jax.Array,
     sp = _pad_to(scale, 0, bn) if scale is not None else None
     out = vta_gemm_pallas(ap, wp, bp, sp, epilogue=epilogue, shift=shift,
                           bm=bm, bn=bn, bk=bk,
-                          interpret=resolve_interpret(interpret))
+                          interpret=interpret)
     return out[:M, :N]
 
 

@@ -12,25 +12,16 @@ from typing import Optional, Tuple
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """`jax.sharding.AxisType` only exists on jax >= 0.5; older releases
-    (0.4.x) default every axis to Auto, so omitting the kwarg is
-    equivalent there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (elastic restarts use this after replanning)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes_of(mesh) -> Tuple[str, ...]:

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.meshctx import get_mesh, shard_map
+from repro.distributed.meshctx import get_mesh
 
 from .layers import linear_apply, linear_init, mlp_apply, mlp_init
 
@@ -247,7 +247,7 @@ def moe_apply(p: Params, cfg, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
             # weights consumed in their stored 2-D layout, no resharding;
             # tokens/gates replicated across data ranks; output identical
             # on every data rank (the replication checker can't prove it
-            # — disabled via check_vma/check_rep)
+            # — disabled via check_vma)
             wi_spec = P(sc.model_axis, dp, None)
             wo_spec = P(sc.model_axis, None, dp)
             xt_spec = P(None, None)
@@ -257,7 +257,7 @@ def moe_apply(p: Params, cfg, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
             wi_spec = P(sc.model_axis, None, None)
             wo_spec = P(sc.model_axis, None, None)
             fe_spec = fg_spec = P(dp)
-        y = shard_map(
+        y = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(xt_spec, fe_spec, fg_spec,
                       wi_spec, wi_spec, wo_spec),
@@ -339,25 +339,9 @@ def _moe_fused_ep(p: Params, cfg, xt: jax.Array, mesh, tp: int,
                  p["shared"]["wo"]["w"]]
         in_specs += [P(None, sc.model_axis), P(None, sc.model_axis),
                      P(sc.model_axis, None)]
-    sm_fn = shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
-                      out_specs=(y_spec, P(), P()))
-
-    # jax 0.4.x shard_map transpose chokes on symbolic-Zero cotangents for
-    # the (usually undifferentiated) aux-stat outputs; custom_vjp
-    # materializes them before they reach the transpose rule.
-    @jax.custom_vjp
-    def _fused_call(*a):
-        return sm_fn(*a)
-
-    def _fused_fwd(*a):
-        out, vjp = jax.vjp(sm_fn, *a)
-        return out, vjp
-
-    def _fused_bwd(vjp, cts):
-        return vjp(cts)
-
-    _fused_call.defvjp(_fused_fwd, _fused_bwd)
-    y, cnt, prob_sum = _fused_call(*args)
+    y, cnt, prob_sum = jax.shard_map(
+        local_fn, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=(y_spec, P(), P()))(*args)
     frac = cnt / jnp.maximum(jnp.sum(cnt), 1.0)
     prob = prob_sum / jnp.maximum(jnp.sum(cnt), 1.0)
     aux = E * jnp.sum(frac * prob)

@@ -65,8 +65,11 @@ def _attention_core(cfg: DecoderConfig, q: np.ndarray, K: np.ndarray,
                     V: np.ndarray, kv_len: int) -> np.ndarray:
     """(d,) int8 query against the first kv_len rows of the (S, d) int8
     caches -> (d,) int8 attention output.  Mode "kernel" routes through
-    the decode_attention op (B=1 GQA decode over the padded cache); mode
-    "numpy" is the dependency-free equivalent.  Both are deterministic."""
+    the decode_attention op (B=1 GQA decode over the padded cache), which
+    resolves interpret mode from the platform exactly as the default
+    ``PallasBackend`` does: Mosaic-compiled on a TPU, interpreted
+    elsewhere.  Mode "numpy" is the dependency-free equivalent.  Both are
+    deterministic."""
     H, D = cfg.n_heads, cfg.head_dim
     if cfg.attention == "kernel":
         import jax.numpy as jnp
@@ -78,7 +81,7 @@ def _attention_core(cfg: DecoderConfig, q: np.ndarray, K: np.ndarray,
         vf = jnp.asarray(V, jnp.float32).reshape(1, cfg.s_max, H, D) \
             / _ATTN_SCALE
         out = decode_attention(qf, kf, vf, jnp.int32(kv_len),
-                               use_pallas=True, interpret=True)
+                               use_pallas=True)
         of = np.asarray(out, np.float32).reshape(cfg.d_model)
     else:
         qf = (q.astype(np.float32) / _ATTN_SCALE).reshape(H, D)

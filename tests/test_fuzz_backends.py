@@ -594,7 +594,7 @@ def _run_one_lowbit(seed: int) -> None:
             outs["buffer"][name], outs["barrier"][name],
             err_msg=f"seed={seed} node={name}: fenced stream diverged "
                     f"from the barrier baseline")
-    # kernel A/B on the Pallas engine: the T-MAC LUT path and the dense
+    # kernel A/B on the Pallas engine: the LUT-GEMM path and the dense
     # MXU path must both reproduce the numpy reference bit-exactly
     compiled = p.compile(use_cache=False)
     for use_lut in (True, False):

@@ -2,7 +2,7 @@
 
 Covers the lowbit tentpole end to end — layout pack/unpack round trips
 (int4/int2/int1, odd widths, padding tails), packed TensorMeta storage
-through both engines, the T-MAC LUT kernel vs the dense GEMM, per-shape
+through both engines, the LUT-GEMM kernel vs the dense GEMM, per-shape
 kernel selection, the VtaLinear bits= knob — plus failing-before /
 passing-after regressions for the three quantize.py bugs the path sits
 on top of (hard-coded int8 clip, overflow-before-clip, empty-input
@@ -168,26 +168,26 @@ def test_calibrate_empty_input_both_branches():
 # LUT-GEMM kernel vs the dense GEMM (bit-exact by construction)
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("bits", [1, 2, 4])
-@pytest.mark.parametrize("group", [2, 4, 8])
-def test_lut_gemm_matches_dense(bits, group):
+@pytest.mark.parametrize("shape", [(1, 32, 16), (4, 144, 130),
+                                   (18, 96, 64)])
+def test_lut_gemm_matches_dense(bits, shape):
     import jax.numpy as jnp
 
     from repro.kernels.lut_gemm import lut_gemm
     from repro.kernels.vta_gemm import vta_gemm
 
     qmin, qmax = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
-    for (M, K, N) in [(1, 32, 16), (4, 144, 130), (18, 96, 64)]:
-        a = RNG.integers(-128, 128, size=(M, K)).astype(np.int8)
-        w = RNG.integers(qmin, qmax + 1, size=(K, N)).astype(np.int8)
-        for ep, sh in [("none", 0), ("requant", 5)]:
-            got = np.asarray(lut_gemm(
-                jnp.asarray(a), jnp.asarray(w), bits=bits, group=group,
-                epilogue=ep, shift=sh, use_pallas=True))
-            want = np.asarray(vta_gemm(jnp.asarray(a), jnp.asarray(w),
-                                       epilogue=ep, shift=sh))
-            np.testing.assert_array_equal(
-                got, want, err_msg=f"bits={bits} group={group} "
-                                   f"shape={(M, K, N)} ep={ep}")
+    M, K, N = shape
+    a = RNG.integers(-128, 128, size=(M, K)).astype(np.int8)
+    w = RNG.integers(qmin, qmax + 1, size=(K, N)).astype(np.int8)
+    for ep, sh in [("none", 0), ("requant", 5)]:
+        got = np.asarray(lut_gemm(
+            jnp.asarray(a), jnp.asarray(w), bits=bits,
+            epilogue=ep, shift=sh, use_pallas=True))
+        want = np.asarray(vta_gemm(jnp.asarray(a), jnp.asarray(w),
+                                   epilogue=ep, shift=sh))
+        np.testing.assert_array_equal(
+            got, want, err_msg=f"bits={bits} shape={shape} ep={ep}")
 
 
 def test_lut_gemm_ref_is_dense():
