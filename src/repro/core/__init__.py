@@ -2,7 +2,7 @@
 scheduler, program-level JIT) as a composable package."""
 from . import autotune, backend, chaos, compiler, conv, driver  # noqa: F401
 from . import hwspec, isa, layout, microop, pipeline_model, program  # noqa: F401
-from . import quantize, runtime, sched, scheduler, serve  # noqa: F401
+from . import quantize, runtime, sched, scheduler, serve, spans  # noqa: F401
 from . import simulator, workloads  # noqa: F401
 from .autotune import TuningCache, TuningRecord  # noqa: F401
 from .chaos import Fault, FaultPlan  # noqa: F401

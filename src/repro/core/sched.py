@@ -63,6 +63,7 @@ from .program import CompiledProgram
 from .serve import (DevicePool, PoolClosed, PoolFuture, Session, SlotDied,
                     WaitTimeout)
 from .simulator import TimingModel, replay_timing
+from .spans import span
 
 #: vmap interpret-mode cliff measured in PR 5: batching more than ~24
 #: tiles into one interpreted vmap launch stops amortizing dispatch
@@ -750,13 +751,14 @@ class Scheduler:
                 # batch across idle and busy slots (permanent desync) —
                 # so anything partial waits for an idle pool.
                 aligned = self._batch_aligned(batch)
-                if aligned and self._last_aligned:
-                    self._work.wait_for(
-                        lambda: self._outstanding <
-                        self.config.pipeline_depth)
-                else:
-                    self._work.wait_for(
-                        lambda: self._outstanding == 0)
+                with span("sched.hold", prog=pi, width=len(batch)):
+                    if aligned and self._last_aligned:
+                        self._work.wait_for(
+                            lambda: self._outstanding <
+                            self.config.pipeline_depth)
+                    else:
+                        self._work.wait_for(
+                            lambda: self._outstanding == 0)
                 self._last_aligned = aligned
                 self._outstanding += 1
             self._release(pi, batch)
@@ -787,7 +789,8 @@ class Scheduler:
             pfs = self.pool._enqueue_batch(
                 [(p.inputs,
                   p.session._state if p.session is not None else None,
-                  prog) for p in batch])
+                  prog) for p in batch],
+                park_s=[released_at - p.future.submit_at for p in batch])
             for p, pf in zip(batch, pfs):
                 p.future.released_at = released_at
                 p.future.pool_future = pf

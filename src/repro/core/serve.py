@@ -110,7 +110,8 @@ from .chaos import FaultPlan
 from .compiler import AccelStep, CpuStep
 from .isa import IsaLayout
 from .program import CompiledProgram
-from .simulator import TimingModel, replay_timing
+from .simulator import RunStats, TimingModel, replay_timing
+from .spans import span, tagged
 
 POLICIES = ("round_robin", "least_loaded")
 
@@ -187,6 +188,12 @@ class PoolFuture:
         self.staging_bytes = 0
         self.attempts = 1               # submissions tried (retries + 1)
         self.done_at: Optional[float] = None  # perf_counter at completion
+        # serving-plane waits, copied onto the RunStats of each segment:
+        # seconds parked in a Scheduler before release (0 when submitted
+        # to the pool directly), and the perf_counter from which the next
+        # segment has been waiting (the enqueue, then each segment's end)
+        self.park_s = 0.0
+        self.queued_at = time.perf_counter()
         self._done = threading.Event()
         self._outputs: Any = None
         self._exc: Optional[BaseException] = None
@@ -631,8 +638,11 @@ class DevicePool:
 
     def _enqueue_batch(self, items: Sequence[Tuple[Dict[str, np.ndarray],
                                                    Optional[_SessionState],
-                                                   CompiledProgram]]
+                                                   CompiledProgram]],
+                       park_s: Optional[Sequence[float]] = None
                        ) -> List[PoolFuture]:
+        """Enqueue `items` atomically; `park_s` gives each one's seconds
+        parked in a Scheduler before this release."""
         for inputs, _, prog in items:
             prog.check_inputs(inputs)
         futs: List[PoolFuture] = []
@@ -654,10 +664,12 @@ class DevicePool:
             if all(s.dead for s in self.slots):
                 raise PoolClosed("every pool slot is dead")
             used: set = set()
-            for inputs, session, prog in items:
+            for i, (inputs, session, prog) in enumerate(items):
                 slot = self._pick_slot(session, avoid=frozenset(used))
                 used.add(slot.id)
                 fut = PoolFuture(slot_id=slot.id, seq=next(self._seq))
+                if park_s is not None:
+                    fut.park_s = park_s[i]
                 slot.queue.append(_Request(
                     future=fut, inputs=dict(inputs), prog=prog,
                     session=session,
@@ -1187,6 +1199,7 @@ class DevicePool:
                         try:
                             req.prog.exec_step(step, device, self.engine,
                                                timing=self.timing)
+                            req.future.queued_at = time.perf_counter()
                             slot.stats.cpu_steps += 1
                         except BaseException as e:
                             host_errs[slot.id] = e
@@ -1261,7 +1274,8 @@ class DevicePool:
                     if self._retries:
                         timeout = max(0.0, min(due for due, _
                                                in self._retries) - now)
-                    self._wake.wait(timeout=timeout)
+                    with span("pool.idle"):
+                        self._wake.wait(timeout=timeout)
             try:
                 self._advance(active)
             except BaseException as e:          # defensive: fail loudly
@@ -1290,8 +1304,9 @@ class DevicePool:
                     device = slot.device
                 try:
                     self._ensure_resident(slot, req)
-                    staged = req.prog.stage_inputs(req.inputs,
-                                                   device=device)
+                    with span("pool.stage_inputs", seq=req.future.seq):
+                        staged = req.prog.stage_inputs(req.inputs,
+                                                       device=device)
                 except BaseException as e:
                     self._retire(slot, error=e)
                     continue
@@ -1441,14 +1456,19 @@ class DevicePool:
             return
         gang = getattr(self.engine, "execute_gang", None)
         prestaged = prog.prestage and step.staged_addr >= 0
+        pk = self._prog_key[id(prog)]
         if gang is not None and len(trios) > 1 and prestaged:
-            statss = gang(prog.spec, [d for _, d, _ in trios],
-                          step.stream, timing=self.timing,
-                          staged_addr=step.staged_addr)
+            start = time.perf_counter()
+            with tagged(prog=pk, seq0=trios[0][2].future.seq):
+                statss = gang(prog.spec, [d for _, d, _ in trios],
+                              step.stream, timing=self.timing,
+                              staged_addr=step.staged_addr)
+            end = time.perf_counter()
             for (slot, _, req), stats in zip(trios, statss):
                 stats.n_join_barriers = step.n_barriers
                 stats.n_buffer_fences = step.n_fences
                 stats.staging_bytes_per_call = req.future.staging_bytes
+                self._record_waits(req.future, stats, start, end)
                 req.future.stats.append(stats)
                 slot.stats.accel_steps += 1
                 slot.stats.ganged_steps += 1
@@ -1457,14 +1477,30 @@ class DevicePool:
                 slot.stats.tile_batches += stats.tile_batches
             return
         for slot, device, req in trios:
-            stats = prog.exec_step(step, device, self.engine,
-                                   timing=self.timing)
+            start = time.perf_counter()
+            with tagged(prog=pk, seq0=req.future.seq):
+                stats = prog.exec_step(step, device, self.engine,
+                                       timing=self.timing)
+            end = time.perf_counter()
             stats.staging_bytes_per_call = req.future.staging_bytes
+            self._record_waits(req.future, stats, start, end)
             req.future.stats.append(stats)
             slot.stats.accel_steps += 1
             slot.stats.max_gang = max(slot.stats.max_gang, 1)
             slot.stats.tiles_resolved += stats.tiles_resolved
             slot.stats.tile_batches += stats.tile_batches
+
+    @staticmethod
+    def _record_waits(fut: PoolFuture, stats: RunStats, start: float,
+                      end: float) -> None:
+        """Put the request's waits before the segment that ran from
+        `start` to `end` on its RunStats: the Scheduler's parking on the
+        first segment, and the time queued since the enqueue or the
+        previous segment's end."""
+        if not fut.stats:
+            stats.park_s = fut.park_s
+        stats.queue_s = start - fut.queued_at
+        fut.queued_at = end
 
     def _apply_faults(self, gang_idx: int, prog: CompiledProgram,
                       group: List[_Slot]) -> None:

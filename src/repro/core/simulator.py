@@ -99,6 +99,22 @@ class RunStats:
     tokens_pushed: int = 0
     backend: str = "simulator"   # which execution engine produced this run
     wall_time_s: float = 0.0     # host wall-clock of the engine (not cycles)
+    # PallasBackend's host seconds inside wall_time_s by phase, each
+    # also a ``vta.engine.<phase>`` span: building and uploading kernel
+    # operands, the kernel calls until they return, and waiting for and
+    # reading back their results.  The rest of wall_time_s is the
+    # engine's own Python (decode, the walk of the stream, tile
+    # bookkeeping, write-back).  Shared gang windows, like wall_time_s
+    stage_s: float = 0.0
+    launch_s: float = 0.0
+    sync_s: float = 0.0
+    # serving-plane waits of the request this segment belongs to, host
+    # seconds: parked in the Scheduler's admission queue before release
+    # to the pool (first segment only; 0 when submitted to the pool
+    # directly), and queued in the pool from enqueue, or from the end of
+    # the request's previous accelerator segment, to this segment's start
+    park_s: float = 0.0
+    queue_s: float = 0.0
     # PallasBackend fast-path accounting (always 0 on the simulator, which
     # has no coalescer): compute instructions absorbed into lazy tiles and
     # resolved through the Pallas kernels vs. ones that fell back to the
@@ -160,7 +176,8 @@ class RunStats:
         for r in runs:
             for f in ("total_cycles", "gemm_macs", "alu_ops",
                       "dram_rd_bytes", "dram_wr_bytes", "tokens_pushed",
-                      "wall_time_s", "coalesced_gemm_insns",
+                      "wall_time_s", "stage_s", "launch_s", "sync_s",
+                      "park_s", "queue_s", "coalesced_gemm_insns",
                       "coalesced_alu_insns", "eager_gemm_insns",
                       "eager_alu_insns", "n_join_barriers",
                       "n_buffer_fences", "staging_bytes_per_call",
