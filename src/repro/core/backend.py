@@ -24,8 +24,9 @@ flags on instructions that are already in the stream and attaches each
 ``dep_pop`` to the next instruction it emits, so every token's producer
 precedes its consumer in program order.  Program order also preserves
 each module's queue order, hence it is one of the legal executions the
-token protocol admits (§2.3) — the PallasBackend verifies this while it
-runs and raises ``DeadlockError`` on streams that violate it.
+token protocol admits (§2.3) — the PallasBackend verifies this once per
+stream, before it runs, and raises ``DeadlockError`` on streams that
+violate it.
 
 jax / Pallas imports are deferred to PallasBackend execution so that
 importing :mod:`repro.core` stays numpy-only.
@@ -36,8 +37,8 @@ import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Protocol, Sequence, Tuple, Union, \
-    runtime_checkable
+from typing import Dict, List, NamedTuple, Optional, Protocol, Sequence, \
+    Tuple, Union, runtime_checkable
 
 import numpy as np
 
@@ -106,7 +107,9 @@ _OUT_EDGES = DEP_OUT_EDGES
 # Shared across backend instances AND serving threads: the pool scheduler
 # may decode concurrently with a foreground call, so every access holds
 # _DECODE_LOCK (pop+reinsert is not atomic under concurrent eviction).
-_DECODE_CACHE: Dict[tuple, List[Insn]] = {}
+# Each entry also holds the stream's interpretation plans, which live
+# and are evicted with it (see PallasBackend._run_gang).
+_DECODE_CACHE: Dict[tuple, "_Decoded"] = {}
 _DECODE_LOCK = threading.Lock()
 # LRU bound on the shared cache: generous by default (a long-lived
 # multi-program server holds a handful of streams per program), but
@@ -143,6 +146,85 @@ def decode_cache_info() -> Dict[str, int]:
 
 
 @dataclass
+class _Decoded:
+    """One decoded stream as the cache holds it: its instructions and,
+    per setting of the switches that shape the engine's analysis, the
+    :class:`_StreamPlan` recorded from its first run.  ``plans`` is None
+    where the engine keeps no plan (``cache_decode=False`` or a cache
+    cap of 0); it is read and written under _DECODE_LOCK."""
+    insns: List[Insn]
+    plans: Optional[Dict[tuple, "_StreamPlan"]] = None
+
+
+class _Step(NamedTuple):
+    """One instruction's entry in a stream plan (``PallasBackend._analyze``):
+    the method that does its work, called as ``(states, statss, insn,
+    *args)``, and the uop SRAM bytes and pending-tile keys it was derived
+    from (None where it read neither), which a replay checks first."""
+    apply: str
+    uops: Optional[bytes] = None
+    pending: Optional[Tuple[int, ...]] = None
+    args: tuple = ()
+
+
+@dataclass(frozen=True)
+class _StreamPlan:
+    """What ``PallasBackend._run_gang`` derives from one stream and the
+    uop SRAM, recorded on the stream's first run and replayed by later
+    ones: one step per instruction, the per-module instruction counts,
+    and the dependence tokens pushed.  Never changed once published."""
+    steps: Tuple[_Step, ...]
+    counts: Tuple[Tuple[str, int], ...]
+    tokens_pushed: int
+
+
+@dataclass(frozen=True, eq=False)
+class _TileShape:
+    """The stream-derived half of a pending tile's resolution plan: its
+    GEMM chunks grouped by grid (each group concatenates along K), the
+    fused requant shift, the structural part of the plan key (``head``;
+    per group ``gsig``: the weight shape and the grid's relative ids and
+    operand shape), and, unless the one group is the tile's own grid,
+    each group's scatter positions in the tile."""
+    n_chunks: int
+    n_alu: int
+    groups: Tuple[Tuple[np.ndarray, Tuple[int, ...]], ...]
+    shift: Optional[int]
+    head: tuple
+    gsig: Tuple[tuple, ...]
+    pos: Optional[Tuple[np.ndarray, ...]]
+
+
+class _TileLayout:
+    """A reset step's slot for its tile's :class:`_TileShape`: filled
+    once, when the run that records the stream's plan first plans the
+    tile, and only read by replays (which plan exactly the tiles the
+    recording run planned)."""
+    __slots__ = ("shape",)
+
+    def __init__(self):
+        self.shape: Optional[_TileShape] = None
+
+
+@dataclass
+class _TilePlan:
+    """One tile's resolution plan for one run (``_plan_tile``): GEMM
+    stages ``wgroups = [(W, [(grid, A, group), ...]), ...]``, one per
+    distinct weight tile, and which grid groups each stage holds."""
+    wgroups: list
+    shape: _TileShape
+    partition: Tuple[Tuple[int, ...], ...]
+
+
+def _frozen(a: np.ndarray) -> np.ndarray:
+    """A read-only copy of an index array a plan step keeps (a copy, so
+    that a view does not keep its whole base array alive)."""
+    a = np.array(a)
+    a.setflags(write=False)
+    return a
+
+
+@dataclass
 class _GemmChunk:
     """One coalesced GEMM instruction: the acc-element grid it wrote and a
     snapshot of its operands.  ``grid`` may equal the owning tile's full
@@ -165,6 +247,8 @@ class _PendingTile:
     chunks: List[_GemmChunk] = field(default_factory=list)
     # epilogue: ("imm", op, imm) | ("tensor", op, (R, C) int32 matrix)
     alu_chain: List[tuple] = field(default_factory=list)
+    # the reset step's shape slot (None: plan the tile from scratch)
+    layout: Optional[_TileLayout] = None
 
 
 class _Clock:
@@ -194,6 +278,13 @@ class _RunState:
     sim: Simulator                          # SRAM state + eager semantics
     clock: _Clock                           # shared by the whole gang
     pending: Dict[int, _PendingTile] = field(default_factory=dict)
+    # GEMM weight operands gathered in this run, by slot: chunks that
+    # gather the same weight rows with no WGT load between them share one
+    # gather.  The analysis (state 0) assigns the slots in `wslots`, by
+    # the rows' ids since the last WGT load; a slot is the position of
+    # the first instruction that gathered them
+    wsnap: Dict[int, np.ndarray] = field(default_factory=dict)
+    wslots: Dict[tuple, int] = field(default_factory=dict)
 
 
 class PallasBackend:
@@ -312,15 +403,15 @@ class PallasBackend:
             raw = devices[0].dram.read(
                 addr, stream.shape[0] * isa.insn_bytes,
                 dtype=np.uint64, shape=(stream.shape[0], isa.insn_words))
-            insns, evicted = self._decode_cached(spec, isa, raw)
-            statss = self._run_gang(spec, devices, insns, clock)
+            dec, evicted = self._decode_cached(spec, isa, raw)
+            statss = self._run_gang(spec, devices, dec, clock)
             wall = time.perf_counter() - t0
         rep = None
         if timing is not None:
             # cycle replay happens OUTSIDE the wall-clock window: the
             # pure-python scheduler pass prices the stream, it is not
             # part of this engine's execution time
-            rep = replay_timing(spec, insns, timing)
+            rep = replay_timing(spec, dec.insns, timing)
         for d, stats in zip(devices, statss):
             d.regs.set_done()
             stats.backend = self.name
@@ -338,24 +429,28 @@ class PallasBackend:
         return statss
 
     def _decode_cached(self, spec: HardwareSpec, isa: IsaLayout,
-                       raw: np.ndarray) -> Tuple[List[Insn], int]:
+                       raw: np.ndarray) -> Tuple[_Decoded, int]:
         """Decode the raw stream words, memoized by content digest: a
         serving loop re-running one pre-staged stream pays the (pure
         python) decode exactly once.  Keyed on the bytes actually read
-        from DRAM, so there is still no side channel.  Returns
-        ``(insns, evicted)`` where `evicted` counts LRU entries this
-        call pushed out of the bounded cache (set_decode_cache_cap)."""
+        from DRAM, so there is still no side channel.  The entry also
+        holds the stream's interpretation plans (:meth:`_run_gang`), so a
+        plan is keyed by the same digest and evicted with its stream;
+        with ``cache_decode=False``, or a cap of 0, neither is kept.
+        Returns ``(decoded, evicted)`` where `evicted` counts LRU entries
+        this call pushed out of the bounded cache
+        (set_decode_cache_cap)."""
         import hashlib
         global _DECODE_EVICTIONS
         if not self.cache_decode:
-            return isa.decode_stream(raw), 0
+            return _Decoded(isa.decode_stream(raw)), 0
         key = (spec, hashlib.sha1(raw.tobytes()).hexdigest())
         with _DECODE_LOCK:
             hit = _DECODE_CACHE.pop(key, None)
             if hit is not None:
                 _DECODE_CACHE[key] = hit   # re-insert: LRU order by last hit
                 return hit, 0
-        insns = isa.decode_stream(raw)
+        dec = _Decoded(isa.decode_stream(raw))
         evicted = 0
         with _DECODE_LOCK:
             while len(_DECODE_CACHE) >= max(1, _DECODE_CACHE_CAP):
@@ -363,180 +458,206 @@ class PallasBackend:
                 _DECODE_CACHE.pop(next(iter(_DECODE_CACHE)))
                 evicted += 1
             if _DECODE_CACHE_CAP > 0:
-                _DECODE_CACHE[key] = insns
+                dec.plans = {}
+                _DECODE_CACHE[key] = dec
             _DECODE_EVICTIONS += evicted
-        return insns, evicted
+        return dec, evicted
 
     # ------------------------------------------------------------------
     def _run_gang(self, spec: HardwareSpec, devices: Sequence[Device],
-                  insns: List[Insn], clock: _Clock) -> List[RunStats]:
+                  dec: _Decoded, clock: _Clock) -> List[RunStats]:
         """Interpret one decoded stream against N per-device states in
         lockstep.  Control flow (structure detection, tile bookkeeping,
         materialization triggers) is data-independent — it derives from
         the stream and the uop SRAM, which are identical across the gang
         — so every decision is taken once on state 0 and applied to all;
         only the operand data differs per state.  Invariant: the states'
-        ``pending`` dicts stay key-synchronized throughout."""
+        ``pending`` dicts stay key-synchronized throughout.
+
+        Each instruction is analysed into a step (:meth:`_analyze`) that
+        the gang then applies.  The first run of a stream, per setting of
+        ``check_tokens``, ``coalesce_subgrids`` and ``batch_tiles``,
+        records its steps, the token check and the per-module counts as a
+        :class:`_StreamPlan` in the stream's decode-cache entry
+        (:meth:`_decode_cached`), built outside _DECODE_LOCK and
+        published under it.  Later runs, at any gang width, replay the
+        steps and do only the data work: DMA, operand gathers (one per
+        weight slot, see ``_RunState.wsnap``), weight-byte grouping,
+        kernel launches and write-back.  Before a
+        replayed step is applied, the uop SRAM bytes and the pending-tile
+        keys it was derived from are checked against state 0; on a
+        mismatch that instruction and the rest of the stream are analysed
+        afresh, so a replay takes no decision the analysis would not.
+        ``RunStats.plan_hit`` is 1 when the whole stream replayed."""
         states = [_RunState(sim=Simulator(spec, d), clock=clock)
                   for d in devices]
         statss = [RunStats(modules={n: ModuleStats()
                                     for n in _MODULE_NAMES.values()})
                   for _ in devices]
-        tokens = {"l2c": 0, "c2l": 0, "c2s": 0, "s2c": 0}
-
-        for insn in insns:
-            q = route_queue(insn)
-            if self.check_tokens:
-                # token protocol is stream-determined: check once
-                for fifo, flag in _IN_EDGES[q]:
-                    if getattr(insn.dep, flag):
-                        if tokens[fifo] == 0:
-                            raise DeadlockError(
-                                f"{type(insn).__name__} pops empty dependence"
-                                f" FIFO {fifo}: stream is not a legal "
-                                f"program-order execution")
-                        tokens[fifo] -= 1
-            for stats in statss:
-                stats.modules[_MODULE_NAMES[q]].insn_count += 1
-
-            if isinstance(insn, FinishInsn):
-                pass
-            elif isinstance(insn, LoadStoreInsn):
-                if insn.opcode == Opcode.STORE:
-                    lo = insn.sram_base
-                    hi = insn.sram_base + insn.y_size * insn.x_size
-                    self._materialize_range(states, lo, hi, statss)
-                    for st, stats in zip(states, statss):
-                        st.sim._do_store(insn, stats)
-                else:
-                    if insn.memory_type in (MemId.ACC, MemId.OUT):
-                        # both land in tile-owned state: ACC loads overwrite
-                        # accumulators, OUT loads overwrite the write-through
-                        # mirror a later STORE reads
-                        width = insn.x_pad_0 + insn.x_size + insn.x_pad_1
-                        rows = insn.y_pad_0 + insn.y_size + insn.y_pad_1
-                        self._materialize_range(
-                            states, insn.sram_base,
-                            insn.sram_base + rows * width, statss)
-                    for st, stats in zip(states, statss):
-                        st.sim._do_load(insn, stats)
-            elif isinstance(insn, GemmInsn):
-                self._gemm(states, insn, statss)
-            elif isinstance(insn, AluInsn):
-                self._alu(states, insn, statss)
-            else:
-                raise TypeError(type(insn))
-
-            if self.check_tokens:
-                for fifo, flag in _OUT_EDGES[q]:
-                    if getattr(insn.dep, flag):
-                        tokens[fifo] += 1
-                        for stats in statss:
-                            stats.tokens_pushed += 1
+        insns = dec.insns
+        switches = (self.check_tokens, self.coalesce_subgrids,
+                    self.batch_tiles)
+        plan = None
+        if dec.plans is not None:
+            with _DECODE_LOCK:
+                plan = dec.plans.get(switches)
+        if plan is None:
+            counts, pushed = self._check_stream(insns)
+            done, steps = 0, [] if dec.plans is not None else None
+        else:
+            counts, pushed = plan.counts, plan.tokens_pushed
+            done, steps = self._replay(plan.steps, insns, states, statss), None
+            if done < len(insns):
+                # the analysis takes over: plan what is pending afresh
+                # and gather weights anew
+                for st in states:
+                    st.wsnap.clear()
+                    for t in st.pending.values():
+                        t.layout = None
+        for i in range(done, len(insns)):
+            step = self._analyze(i, insns[i], states[0])
+            if steps is not None:
+                steps.append(step)
+            getattr(self, step.apply)(states, statss, insns[i], *step.args)
 
         # a well-formed stream leaves nothing pending, but flush anyway so
         # partial streams (no FINISH/store) still leave coherent SRAM
         if states[0].pending:
             self._materialize_group(states, list(states[0].pending), statss,
-                                    batch_peers=False)
+                                    None)
+        if steps is not None:
+            made = _StreamPlan(tuple(steps), counts, pushed)
+            with _DECODE_LOCK:
+                dec.plans.setdefault(switches, made)
+        hit = int(plan is not None and done == len(insns))
+        for stats in statss:
+            for nm, n in counts:
+                stats.modules[nm].insn_count = n
+            stats.tokens_pushed = pushed
+            stats.plan_hit = hit
         return statss
 
-    # ------------------------------------------------------------------
-    # pending-tile bookkeeping
-    # ------------------------------------------------------------------
-    def _materialize_range(self, states: Sequence[_RunState], lo: int,
-                           hi: int, statss: Sequence[RunStats]) -> None:
-        st0 = states[0]
-        need = []
-        for base in list(st0.pending):
-            t = st0.pending[base]
-            if t.indices[0] < hi and lo <= t.indices[-1]:
-                if np.any((t.indices >= lo) & (t.indices < hi)):
-                    need.append(base)
-        if need:
-            # store / ACC-load trigger: peer virtual-thread tiles of the
-            # same op are complete here (their epilogues precede the
-            # group's first store in program order) — batch them along
-            self._materialize_group(states, need, statss, batch_peers=True)
+    def _check_stream(self, insns: Sequence[Insn]
+                      ) -> Tuple[Tuple[Tuple[str, int], ...], int]:
+        """Per-module instruction counts and the dependence tokens pushed
+        (counted under ``check_tokens``, as the token protocol is checked).
+        The protocol is stream-determined, so it is checked once per
+        stream, before any instruction runs: a pop from an empty FIFO
+        raises DeadlockError."""
+        counts = {n: 0 for n in _MODULE_NAMES.values()}
+        tokens = {"l2c": 0, "c2l": 0, "c2s": 0, "s2c": 0}
+        pushed = 0
+        for insn in insns:
+            q = route_queue(insn)
+            counts[_MODULE_NAMES[q]] += 1
+            if not self.check_tokens:
+                continue
+            for fifo, flag in _IN_EDGES[q]:
+                if getattr(insn.dep, flag):
+                    if tokens[fifo] == 0:
+                        raise DeadlockError(
+                            f"{type(insn).__name__} pops empty dependence"
+                            f" FIFO {fifo}: stream is not a legal "
+                            f"program-order execution")
+                    tokens[fifo] -= 1
+            for fifo, flag in _OUT_EDGES[q]:
+                if getattr(insn.dep, flag):
+                    tokens[fifo] += 1
+                    pushed += 1
+        return tuple(counts.items()), pushed
 
-    def _materialize_indices(self, states: Sequence[_RunState],
-                             idx: np.ndarray,
-                             statss: Sequence[RunStats]) -> None:
+    def _replay(self, steps: Sequence[_Step], insns: Sequence[Insn],
+                states: Sequence[_RunState],
+                statss: Sequence[RunStats]) -> int:
+        """Apply recorded steps while the uop bytes and pending-tile keys
+        each was derived from still hold; returns how many were applied."""
         st0 = states[0]
-        need = [base for base in list(st0.pending)
-                if np.isin(idx, st0.pending[base].indices,
-                           assume_unique=False).any()]
-        if need:
-            # eager-fallback trigger: other pending tiles may still be
-            # mid-accumulation, resolve only what is forced
-            self._materialize_group(states, need, statss, batch_peers=False)
+        uop_sram = st0.sim.uop_sram
+        for i, (insn, (apply, uops, pending, args)) in enumerate(
+                zip(insns, steps)):
+            if uops is not None and \
+                    uop_sram[insn.uop_bgn:insn.uop_end].tobytes() != uops:
+                return i
+            if pending is not None and tuple(st0.pending) != pending:
+                return i
+            getattr(self, apply)(states, statss, insn, *args)
+        return len(steps)
 
-    def _materialize_group(self, states: Sequence[_RunState],
-                           keys: Sequence[int], statss: Sequence[RunStats],
-                           batch_peers: bool) -> None:
-        """Resolve the pending tiles at `keys` in EVERY gang state —
-        plus, with batch_peers, any structurally-identical pending peers
-        — grouping same-plan tiles into ONE (vmapped) kernel launch per
-        GEMM stage instead of one launch per tile.  With a gang of N the
-        launch batches N× the tiles: the per-launch dispatch cost is
-        paid once for the pool (sharded batch dispatch)."""
-        plan0: Dict[int, tuple] = {}     # state-0 plans, keyed by base
-        if batch_peers and self.batch_tiles and states[0].pending:
-            # peer sweep decided on state 0 by structural match; the
-            # chosen KEYS are popped from every state so the pending
-            # dicts stay synchronized.  A peer whose plan key diverges
-            # on another state (e.g. coincidentally-equal weight bytes
-            # merged there) still resolves correctly — it just lands in
-            # its own launch group below.
-            sigs, pre_sigs = set(), set()
-            for k in keys:
-                t = states[0].pending[k]
-                if t.chunks:
-                    plan0[k] = self._plan_tile(t)
-                    sigs.add(self._plan_key(t, plan0[k]))
-                    pre_sigs.add(self._pre_key(t))
-            peer_keys = []
-            if sigs:
-                for base in list(states[0].pending):
-                    if base in keys:
-                        continue
-                    peer = states[0].pending[base]
-                    if not peer.chunks or self._pre_key(peer) not in pre_sigs:
-                        continue
-                    plan = self._plan_tile(peer)
-                    if self._plan_key(peer, plan) in sigs:
-                        peer_keys.append(base)
-                        plan0[base] = plan
-            keys = list(keys) + peer_keys
-        entries: List[Tuple[int, int, _PendingTile]] = \
-            [(si, k, st.pending.pop(k))
-             for si, st in enumerate(states) for k in keys]
-        if not self.batch_tiles:
-            for si, _, t in entries:
-                self._materialize(states[si], t, statss[si])
-            return
-        groups: Dict[tuple, List[Tuple[int, _PendingTile, tuple]]] = {}
-        for si, k, t in entries:
-            if t.chunks:
-                plan = plan0[k] if si == 0 and k in plan0 \
-                    else self._plan_tile(t)
-                groups.setdefault(self._plan_key(t, plan), []).append(
-                    (si, t, plan))
+    # ------------------------------------------------------------------
+    # analysis: what an instruction does, from the stream, the uop SRAM
+    # and the pending tiles of state 0
+    # ------------------------------------------------------------------
+    def _analyze(self, i: int, insn: Insn, st0: _RunState) -> _Step:
+        """The step of instruction `i`, changing no machine state."""
+        if isinstance(insn, FinishInsn):
+            return _Step("_ap_nop")
+        if isinstance(insn, LoadStoreInsn):
+            if insn.opcode == Opcode.STORE:
+                lo = insn.sram_base
+                hi = insn.sram_base + insn.y_size * insn.x_size
+            elif insn.memory_type in (MemId.ACC, MemId.OUT):
+                # both land in tile-owned state: ACC loads overwrite
+                # accumulators, OUT loads overwrite the write-through
+                # mirror a later STORE reads
+                width = insn.x_pad_0 + insn.x_size + insn.x_pad_1
+                rows = insn.y_pad_0 + insn.y_size + insn.y_pad_1
+                lo, hi = insn.sram_base, insn.sram_base + rows * width
             else:
-                self._materialize(states[si], t, statss[si])  # reset/ALU-only
-        for grp in groups.values():
-            tiles_g = [t for _, t, _ in grp]
-            plans_g = [p for _, _, p in grp]
-            stats_g = [statss[si] for si, _, _ in grp]
-            accs = self._resolve_tiles(tiles_g, plans_g, stats_g,
-                                       states[0].sim.spec, states[0].clock)
-            for (si, tile, _), acc in zip(grp, accs):
-                self._writeback(states[si], tile, acc, statss[si])
+                if insn.memory_type == MemId.WGT:
+                    st0.wslots.clear()      # weight rows change: new slots
+                return _Step("_ap_commit")
+            return _Step("_ap_commit", None, tuple(st0.pending),
+                         self._range_need(st0, lo, hi))
+        if not isinstance(insn, (GemmInsn, AluInsn)):
+            raise TypeError(type(insn))
+        sim0 = st0.sim
+        words = sim0.uop_sram[insn.uop_bgn:insn.uop_end]
+        uops = sim0.uop_layout.decode_kernel(words)
+        if not uops or insn.iter_out == 0 or insn.iter_in == 0:
+            return _Step("_ap_nop", words.tobytes())
+        analyze = self._analyze_gemm if isinstance(insn, GemmInsn) \
+            else self._analyze_alu
+        apply, args = analyze(i, st0, insn, uops)
+        return _Step(apply, words.tobytes(), tuple(st0.pending), args)
+
+    def _range_need(self, st0: _RunState, lo: int, hi: int) -> tuple:
+        """``(keys, peers)`` of a store or ACC/OUT load over SRAM
+        [lo, hi): the pending tiles it forces, and their peer candidates
+        (:meth:`_peer_candidates`); () when it forces none."""
+        need = tuple(
+            base for base, t in st0.pending.items()
+            if t.indices[0] < hi and lo <= t.indices[-1]
+            and np.any((t.indices >= lo) & (t.indices < hi)))
+        if not need:
+            return ()
+        # store / ACC-load trigger: peer virtual-thread tiles of the
+        # same op are complete here (their epilogues precede the
+        # group's first store in program order) — batch them along
+        return need, self._peer_candidates(st0, need)
+
+    def _peer_candidates(self, st0: _RunState, keys: Sequence[int]
+                         ) -> Optional[Tuple[int, ...]]:
+        """Pending tiles structurally like a forced one (``_pre_key``),
+        which may resolve in the same launches; whether they do is the
+        plan-key match, which reads weight bytes and is made in every
+        run (:meth:`_materialize_group`).  None: no peer sweep."""
+        if not self.batch_tiles:
+            return None
+        pre = {self._pre_key(st0.pending[k]) for k in keys
+               if st0.pending[k].chunks}
+        if not pre:
+            return ()
+        return tuple(base for base, t in st0.pending.items()
+                     if base not in keys and t.chunks
+                     and self._pre_key(t) in pre)
 
     @staticmethod
-    def _overlaps_pending(st: _RunState, idx: np.ndarray) -> bool:
-        return any(np.isin(idx, t.indices).any()
-                   for t in st.pending.values())
+    def _tiles_holding(st0: _RunState, idx: np.ndarray) -> Tuple[int, ...]:
+        """The keys of the pending tiles holding any of the acc ids
+        `idx`."""
+        idx = np.unique(idx)
+        return tuple(base for base, t in st0.pending.items()
+                     if np.isin(idx, t.indices).any())
 
     @staticmethod
     def _decode_structure(insn, uops, dsts, srcs, wgts):
@@ -580,85 +701,43 @@ class PallasBackend:
                 return k, t
         return None
 
-    # ------------------------------------------------------------------
-    # GEMM
-    # ------------------------------------------------------------------
-    def _gemm(self, states: Sequence[_RunState], insn: GemmInsn,
-              statss: Sequence[RunStats]) -> None:
-        sim0 = states[0].sim
-        uops = sim0.uop_layout.decode_kernel(
-            sim0.uop_sram[insn.uop_bgn:insn.uop_end])
-        if not uops or insn.iter_out == 0 or insn.iter_in == 0:
-            return
-        dsts, srcs, wgts = sim0._affine_indices(insn, uops)
+    def _analyze_gemm(self, i: int, st0: _RunState, insn: GemmInsn, uops
+                      ) -> Tuple[str, tuple]:
+        dsts, srcs, wgts = st0.sim._affine_indices(insn, uops)
         struct = self._decode_structure(insn, uops, dsts, srcs, wgts)
         if struct is None:
-            self._materialize_indices(states, np.unique(dsts), statss)
-            for st, stats in zip(states, statss):
-                st.sim._do_gemm(insn, stats)
-                stats.eager_gemm_insns += 1
-            return
+            return "_ap_commit", (self._tiles_holding(st0, dsts),)
         grid, src_idx, wgt_idx = struct
-
         if insn.reset:
             # reset opens a fresh accumulation tile; whatever overlapped
             # before is dead (never observed) for an exact-region match,
             # and must be resolved first otherwise
             base = int(grid.min())
-            prev = states[0].pending.get(base)
-            if prev is not None and prev.grid.shape == grid.shape \
-                    and (prev.grid == grid).all():
-                for st in states:
-                    del st.pending[base]
-            else:
-                self._materialize_indices(states, np.unique(grid), statss)
-            for st in states:
-                st.pending[base] = _PendingTile(
-                    grid=grid, indices=np.unique(grid))
-            return
-
-        found = self._find_containing(states[0], grid)
+            prev = st0.pending.get(base)
+            drop = prev is not None and prev.grid.shape == grid.shape \
+                and bool((prev.grid == grid).all())
+            need = () if drop else self._tiles_holding(st0, grid)
+            return "_ap_reset", (
+                base, drop, need, _frozen(grid), _frozen(np.unique(grid)),
+                _TileLayout())
+        found = self._find_containing(st0, grid)
         if found is None or found[1].alu_chain:
             # accumulate-onto-existing-values, post-epilogue, or
             # partially-overlapping GEMM: resolve lazies, then run the
             # eager oracle semantics
-            self._materialize_indices(states, np.unique(dsts), statss)
-            for st, stats in zip(states, statss):
-                st.sim._do_gemm(insn, stats)
-                stats.eager_gemm_insns += 1
-            return
-        key = found[0]
-        s = sim0.spec
-        U = src_idx.shape[1]
-        for st, stats in zip(states, statss):
-            sim = st.sim
-            # snapshot operands NOW: virtual threading will overwrite
-            # these SRAM contexts before the tile is stored
-            A = sim.inp_sram[src_idx]        # (io, U, batch, block_in)
-            Wm = sim.wgt_sram[wgt_idx]       # (ii, U, block_out, block_in)
-            A2 = np.ascontiguousarray(
-                A.transpose(0, 2, 1, 3).reshape(grid.shape[0] * s.batch,
-                                                U * s.block_in))
-            W2 = np.ascontiguousarray(
-                Wm.transpose(0, 2, 1, 3).reshape(grid.shape[1] * s.block_out,
-                                                 U * s.block_in))
-            st.pending[key].chunks.append(_GemmChunk(grid=grid, a=A2, w=W2))
-            stats.coalesced_gemm_insns += 1
-            stats.gemm_macs += (grid.size * U * s.batch
-                                * s.block_in * s.block_out)
+            return "_ap_commit", (self._tiles_holding(st0, dsts),)
+        s = st0.sim.spec
+        macs = grid.size * src_idx.shape[1] * s.batch * s.block_in \
+            * s.block_out
+        wslot = st0.wslots.setdefault((wgt_idx.shape, wgt_idx.tobytes()), i)
+        return "_ap_chunk", (
+            found[0], _frozen(grid), _frozen(src_idx), _frozen(wgt_idx),
+            wslot, macs)
 
-    # ------------------------------------------------------------------
-    # ALU
-    # ------------------------------------------------------------------
-    def _alu(self, states: Sequence[_RunState], insn: AluInsn,
-             statss: Sequence[RunStats]) -> None:
-        sim0 = states[0].sim
-        uops = sim0.uop_layout.decode_kernel(
-            sim0.uop_sram[insn.uop_bgn:insn.uop_end])
-        if not uops or insn.iter_out == 0 or insn.iter_in == 0:
-            return
-        s = sim0.spec
-        dsts, srcs, _ = sim0._affine_indices(insn, uops)
+    def _analyze_alu(self, i: int, st0: _RunState, insn: AluInsn, uops
+                     ) -> Tuple[str, tuple]:
+        s = st0.sim.spec
+        dsts, srcs, _ = st0.sim._affine_indices(insn, uops)
         if len(uops) == 1:
             # tile-epilogue shape: one uop, each dst written exactly once;
             # src may be any affine function of the loop indices (the bias
@@ -666,52 +745,118 @@ class PallasBackend:
             grid = dsts.reshape(insn.iter_out, insn.iter_in)
             src_grid = srcs.reshape(insn.iter_out, insn.iter_in)
             base = int(grid.min())
-            tile0 = states[0].pending.get(base)
-            if (tile0 is not None and np.unique(grid).size == grid.size
+            tile0 = st0.pending.get(base)
+            distinct = np.unique(grid).size == grid.size
+            ops = grid.size * s.batch * s.block_out
+            if (tile0 is not None and distinct
                     and tile0.grid.shape == grid.shape
                     and (tile0.grid == grid).all()):
                 op = _ALU_NAMES[insn.alu_opcode]
                 if insn.use_imm:
-                    for st, stats in zip(states, statss):
-                        st.pending[base].alu_chain.append(
-                            ("imm", op, int(insn.imm)))
-                        stats.alu_ops += grid.size * s.batch * s.block_out
-                        stats.coalesced_alu_insns += 1
-                    return
+                    return "_ap_alu_chain", (base, op, None, ops)
                 # tensor-tensor: src must be readable now (eager region)
-                if not self._overlaps_pending(states[0],
-                                              np.unique(src_grid)):
-                    for st, stats in zip(states, statss):
-                        src_mat = self._to_matrix(
-                            st.sim.acc_sram[src_grid], s)
-                        st.pending[base].alu_chain.append(
-                            ("tensor", op, src_mat))
-                        stats.alu_ops += grid.size * s.batch * s.block_out
-                        stats.coalesced_alu_insns += 1
-                    return
+                if not self._tiles_holding(st0, src_grid):
+                    return "_ap_alu_chain", (
+                        base, op, _frozen(src_grid), ops)
             # vector-ALU fast path: a dense single-uop op over the *eager*
             # region (no pending lazy tile) — e.g. the chunked
             # schedule_vector_binop stream — resolves through one
             # tensor_alu Pallas call instead of the eager per-row loop
-            if (np.unique(grid).size == grid.size
-                    and not self._overlaps_pending(states[0],
-                                                   np.unique(dsts))
+            if (distinct and not self._tiles_holding(st0, dsts)
                     and (insn.use_imm
-                         or not self._overlaps_pending(states[0],
-                                                       np.unique(srcs)))):
-                self._alu_eager_region(states, insn, grid, src_grid, statss)
-                return
+                         or not self._tiles_holding(st0, srcs))):
+                return "_alu_eager_region", (
+                    _frozen(grid), _frozen(src_grid),
+                    _frozen(np.unique(grid)), ops)
         # fallback: eager semantics on materialized state
-        need = np.unique(dsts if insn.use_imm
-                         else np.concatenate([dsts, srcs]))
-        self._materialize_indices(states, need, statss)
-        for st, stats in zip(states, statss):
-            st.sim._do_alu(insn, stats)
-            stats.eager_alu_insns += 1
+        return "_ap_commit", (self._tiles_holding(
+            st0, dsts if insn.use_imm else np.concatenate([dsts, srcs])),)
 
-    def _alu_eager_region(self, states: Sequence[_RunState], insn: AluInsn,
+    # ------------------------------------------------------------------
+    # steps: the work of one instruction on the gang's states
+    # ------------------------------------------------------------------
+    def _ap_nop(self, states: Sequence[_RunState],
+                statss: Sequence[RunStats], insn: Insn) -> None:
+        pass
+
+    def _ap_commit(self, states: Sequence[_RunState],
+                   statss: Sequence[RunStats], insn: Insn,
+                   keys: Sequence[int] = (),
+                   peers: Optional[Sequence[int]] = None) -> None:
+        """Resolve the pending tiles at `keys` (and matching `peers`),
+        then run the instruction with the simulator's semantics: a DMA,
+        or a compute instruction the fast path does not take (eager)."""
+        if keys:
+            self._materialize_group(states, keys, statss, peers)
+        gemm = int(isinstance(insn, GemmInsn))
+        alu = int(isinstance(insn, AluInsn))
+        for st, stats in zip(states, statss):
+            st.sim._commit(insn, stats)
+            stats.eager_gemm_insns += gemm
+            stats.eager_alu_insns += alu
+
+    def _ap_reset(self, states: Sequence[_RunState],
+                  statss: Sequence[RunStats], insn: GemmInsn, base: int,
+                  drop: bool, keys: Sequence[int], grid: np.ndarray,
+                  indices: np.ndarray, layout: _TileLayout) -> None:
+        """Open the tile at `base` in every state, first dropping a dead
+        exact-match predecessor or resolving the tiles at `keys`."""
+        if drop:
+            for st in states:
+                del st.pending[base]
+        elif keys:
+            self._materialize_group(states, keys, statss, None)
+        for st in states:
+            st.pending[base] = _PendingTile(grid=grid, indices=indices,
+                                            layout=layout)
+
+    def _ap_chunk(self, states: Sequence[_RunState],
+                  statss: Sequence[RunStats], insn: GemmInsn, key: int,
+                  grid: np.ndarray, src_idx: np.ndarray,
+                  wgt_idx: np.ndarray, wslot: int, macs: int) -> None:
+        """Add one coalesced GEMM chunk to the tile at `key` in every
+        state; the weight operand is gathered once per slot `wslot`."""
+        s = states[0].sim.spec
+        U = src_idx.shape[1]
+        for st, stats in zip(states, statss):
+            sim = st.sim
+            # snapshot operands NOW: virtual threading will overwrite
+            # these SRAM contexts before the tile is stored
+            A = sim.inp_sram[src_idx]        # (io, U, batch, block_in)
+            A2 = np.ascontiguousarray(
+                A.transpose(0, 2, 1, 3).reshape(grid.shape[0] * s.batch,
+                                                U * s.block_in))
+            W2 = st.wsnap.get(wslot)
+            if W2 is None:
+                Wm = sim.wgt_sram[wgt_idx]   # (ii, U, block_out, block_in)
+                W2 = st.wsnap[wslot] = np.ascontiguousarray(
+                    Wm.transpose(0, 2, 1, 3).reshape(
+                        grid.shape[1] * s.block_out, U * s.block_in))
+            st.pending[key].chunks.append(_GemmChunk(grid=grid, a=A2, w=W2))
+            stats.coalesced_gemm_insns += 1
+            stats.gemm_macs += macs
+
+    def _ap_alu_chain(self, states: Sequence[_RunState],
+                      statss: Sequence[RunStats], insn: AluInsn, base: int,
+                      op: str, src_grid: Optional[np.ndarray],
+                      ops: int) -> None:
+        """Append one op to the epilogue of the tile at `base`: an
+        immediate, or (with `src_grid`) a tensor operand read now."""
+        s = states[0].sim.spec
+        for st, stats in zip(states, statss):
+            if src_grid is None:
+                entry = ("imm", op, int(insn.imm))
+            else:
+                entry = ("tensor", op,
+                         self._to_matrix(st.sim.acc_sram[src_grid], s))
+            st.pending[base].alu_chain.append(entry)
+            stats.alu_ops += ops
+            stats.coalesced_alu_insns += 1
+
+    def _alu_eager_region(self, states: Sequence[_RunState],
+                          statss: Sequence[RunStats], insn: AluInsn,
                           grid: np.ndarray, src_grid: np.ndarray,
-                          statss: Sequence[RunStats]) -> None:
+                          touched: np.ndarray, ops: int) -> None:
         """Run one dense ALU instruction over already-materialized
         accumulator state through the tensor_alu Pallas kernel, keeping the
         §2.5 write-through OUT mirror coherent.  Gang members row-stack
@@ -743,14 +888,73 @@ class PallasBackend:
         with clock.phase("sync"):
             out = np.asarray(out, dtype=np.int32)
         io, ii = grid.shape
-        touched = np.unique(grid)
         for i, (st, stats) in enumerate(zip(states, statss)):
             sim = st.sim
             sim.acc_sram[grid] = self._from_matrix(
                 out[i * R:(i + 1) * R], io, ii, s)
             sim.out_sram[touched] = sim.acc_sram[touched].astype(np.int8)
-            stats.alu_ops += grid.size * s.batch * s.block_out
+            stats.alu_ops += ops
             stats.coalesced_alu_insns += 1
+
+    # ------------------------------------------------------------------
+    # pending-tile resolution
+    # ------------------------------------------------------------------
+    def _materialize_group(self, states: Sequence[_RunState],
+                           keys: Sequence[int], statss: Sequence[RunStats],
+                           peers: Optional[Sequence[int]]) -> None:
+        """Resolve the pending tiles at `keys` in EVERY gang state —
+        plus those of the candidate `peers` whose plan matches one of
+        theirs — grouping same-plan tiles into ONE (vmapped) kernel
+        launch per GEMM stage instead of one launch per tile.  With a
+        gang of N the launch batches N× the tiles: the per-launch
+        dispatch cost is paid once for the pool (sharded batch
+        dispatch)."""
+        st0 = states[0]
+        plan0: Dict[int, _TilePlan] = {}     # state-0 plans, keyed by base
+        if peers:
+            # peer match decided on state 0; the chosen KEYS are popped
+            # from every state so the pending dicts stay synchronized.  A
+            # peer whose plan key diverges on another state (e.g.
+            # coincidentally-equal weight bytes merged there) still
+            # resolves correctly — it just lands in its own launch group
+            # below.
+            sigs = set()
+            for k in keys:
+                t = st0.pending[k]
+                if t.chunks:
+                    plan0[k] = self._plan_tile(t)
+                    sigs.add(self._plan_key(plan0[k]))
+            found = []
+            for base in peers:
+                plan = self._plan_tile(st0.pending[base])
+                if self._plan_key(plan) in sigs:
+                    found.append(base)
+                    plan0[base] = plan
+            keys = list(keys) + found
+        entries: List[Tuple[int, int, _PendingTile]] = \
+            [(si, k, st.pending.pop(k))
+             for si, st in enumerate(states) for k in keys]
+        if not self.batch_tiles:
+            for si, _, t in entries:
+                self._materialize(states[si], t, statss[si])
+            return
+        groups: Dict[tuple, List[Tuple[int, _PendingTile, _TilePlan]]] = {}
+        for si, k, t in entries:
+            if t.chunks:
+                plan = plan0[k] if si == 0 and k in plan0 \
+                    else self._plan_tile(t)
+                groups.setdefault(self._plan_key(plan), []).append(
+                    (si, t, plan))
+            else:
+                self._materialize(states[si], t, statss[si])  # reset/ALU-only
+        for grp in groups.values():
+            tiles_g = [t for _, t, _ in grp]
+            plans_g = [p for _, _, p in grp]
+            stats_g = [statss[si] for si, _, _ in grp]
+            accs = self._resolve_tiles(tiles_g, plans_g, stats_g,
+                                       st0.sim.spec, st0.clock)
+            for (si, tile, _), acc in zip(grp, accs):
+                self._writeback(states[si], tile, acc, statss[si])
 
     # ------------------------------------------------------------------
     # tile resolution through the Pallas kernels
@@ -810,47 +1014,89 @@ class PallasBackend:
             return shift
         return None
 
-    def _plan_tile(self, tile: _PendingTile):
+    def _tile_shape(self, tile: _PendingTile) -> _TileShape:
+        """The stream-derived half of `tile`'s plan: read from its reset
+        step's layout when that holds it for these chunks and epilogue,
+        else worked out (and kept there, if the slot is empty)."""
+        lay = tile.layout
+        sh = lay.shape if lay is not None else None
+        if sh is not None and sh.n_chunks == len(tile.chunks) \
+                and sh.n_alu == len(tile.alu_chain):
+            return sh
+        groups: List[Tuple[np.ndarray, List[int]]] = []
+        index: Dict[tuple, int] = {}
+        for i, c in enumerate(tile.chunks):
+            key = (c.grid.shape, c.grid.tobytes())
+            if key in index:
+                groups[index[key]][1].append(i)
+            else:
+                index[key] = len(groups)
+                groups.append((c.grid, [i]))
+        ids = np.concatenate([g.ravel() for g, _ in groups])
+        disjoint = np.unique(ids).size == ids.size
+        shift = self._requant_shift(tile.alu_chain) if disjoint else None
+        base = int(tile.indices[0])
+        alu_sig = tuple((k, op, x) if k == "imm" else (k, op, x.shape)
+                        for k, op, x in tile.alu_chain)
+        gsig = []
+        for g, idx in groups:
+            cs = [tile.chunks[i] for i in idx]
+            w_shape = (cs[0].w.shape[0], sum(c.w.shape[1] for c in cs))
+            a_shape = (cs[0].a.shape[0], sum(c.a.shape[1] for c in cs))
+            gsig.append((w_shape, (g.shape, (g - base).tobytes(), a_shape)))
+        g0 = groups[0][0]
+        pos = None
+        if not (len(groups) == 1 and g0.shape == tile.grid.shape
+                and (g0 == tile.grid).all()):
+            flat = tile.grid.ravel()
+            order = np.argsort(flat)
+            pos = tuple(order[np.searchsorted(flat, g.ravel(), sorter=order)]
+                        for g, _ in groups)
+        sh = _TileShape(n_chunks=len(tile.chunks), n_alu=len(tile.alu_chain),
+                        groups=tuple((g, tuple(idx)) for g, idx in groups),
+                        shift=shift,
+                        head=(shift, tile.grid.shape,
+                              (tile.grid - base).tobytes(), alu_sig),
+                        gsig=tuple(gsig), pos=pos)
+        if lay is not None and lay.shape is None:
+            lay.shape = sh
+        return sh
+
+    def _plan_tile(self, tile: _PendingTile) -> _TilePlan:
         """Stage 1+2 of tile resolution (pure bookkeeping, no kernels):
         chunks that accumulated onto the *same* grid (the reduction loop)
         concatenate along K; grids that multiplied the *same* weight tile
         — the direct-conv structure, one instruction per output row —
-        row-stack into one GEMM per distinct weight tile.  Returns
-        (wgroups, shift): wgroups = [(W, [(grid, A), ...]), ...]; shift is
-        the requant shift when the ALU chain fuses into the kernel
-        epilogue (chunk grids pairwise disjoint + canonical shr/clip
-        chain), else None."""
-        merged: List[Tuple[np.ndarray, List[np.ndarray], List[np.ndarray]]] \
-            = []
-        index: Dict[tuple, int] = {}
-        for c in tile.chunks:
-            key = (c.grid.shape, c.grid.tobytes())
-            if key in index:
-                _, As, Ws = merged[index[key]]
-                As.append(c.a)
-                Ws.append(c.w)
-            else:
-                index[key] = len(merged)
-                merged.append((c.grid, [c.a], [c.w]))
-        groups = [(g, np.concatenate(As, axis=1), np.concatenate(Ws, axis=1))
-                  for g, As, Ws in merged]
-
-        n_ids = sum(g.size for g, _, _ in groups)
-        disjoint = np.unique(
-            np.concatenate([g.ravel() for g, _, _ in groups])).size == n_ids
-        shift = self._requant_shift(tile.alu_chain) if disjoint else None
-
+        row-stack into one GEMM per distinct weight tile.  The grouping
+        by grid is the tile's :class:`_TileShape`; the grouping by weight
+        bytes is made in every run.  The shape's ``shift`` is the requant
+        shift when the ALU chain fuses into the kernel epilogue (chunk
+        grids pairwise disjoint + canonical shr/clip chain), else None."""
+        sh = self._tile_shape(tile)
+        ch = tile.chunks
         wgroups: List[Tuple[np.ndarray,
-                            List[Tuple[np.ndarray, np.ndarray]]]] = []
+                            List[Tuple[np.ndarray, np.ndarray, int]]]] = []
+        parts: List[List[int]] = []
+        # a stage is found by weight bytes; groups built from the very
+        # same weight arrays (a shared gather) are found without them
         windex: Dict[tuple, int] = {}
-        for g, A, W in groups:
-            key = (W.shape, W.tobytes())
-            if key in windex:
-                wgroups[windex[key]][1].append((g, A))
-            else:
-                windex[key] = len(wgroups)
-                wgroups.append((W, [(g, A)]))
-        return wgroups, shift
+        same: Dict[tuple, int] = {}
+        for j, (g, idx) in enumerate(sh.groups):
+            A = ch[idx[0]].a if len(idx) == 1 else \
+                np.concatenate([ch[i].a for i in idx], axis=1)
+            ids = tuple(id(ch[i].w) for i in idx)
+            w = same.get(ids)
+            if w is None:
+                W = ch[idx[0]].w if len(idx) == 1 else \
+                    np.concatenate([ch[i].w for i in idx], axis=1)
+                w = windex.setdefault((W.shape, W.tobytes()), len(wgroups))
+                same[ids] = w
+                if w == len(wgroups):
+                    wgroups.append((W, []))
+                    parts.append([])
+            wgroups[w][1].append((g, A, j))
+            parts[w].append(j)
+        return _TilePlan(wgroups, sh, tuple(tuple(p) for p in parts))
 
     @staticmethod
     def _pre_key(tile: _PendingTile) -> tuple:
@@ -864,25 +1110,19 @@ class PallasBackend:
                       for k, op, x in tile.alu_chain))
 
     @staticmethod
-    def _plan_key(tile: _PendingTile, plan) -> tuple:
+    def _plan_key(plan: _TilePlan) -> tuple:
         """Structural signature of a tile's resolution plan.  Tiles with
         equal keys (peer virtual-thread contexts of one op) run the same
         kernel shapes over the same relative index structure and can be
         resolved by ONE vmapped launch per GEMM stage."""
-        wgroups, shift = plan
-        base = int(tile.indices[0])
-        alu_sig = tuple(
-            (k, op, x) if k == "imm" else (k, op, x.shape)
-            for k, op, x in tile.alu_chain)
-        return (shift, tile.grid.shape, (tile.grid - base).tobytes(),
-                alu_sig,
-                tuple((W.shape,
-                       tuple((g.shape, (g - base).tobytes(), A.shape)
-                             for g, A in parts))
-                      for W, parts in wgroups))
+        sh = plan.shape
+        return sh.head + (tuple(
+            (sh.gsig[p[0]][0], tuple(sh.gsig[j][1] for j in p))
+            for p in plan.partition),)
 
     def _resolve_tiles(self, tiles: Sequence[_PendingTile],
-                       plans: Sequence[tuple], statss: Sequence[RunStats],
+                       plans: Sequence[_TilePlan],
+                       statss: Sequence[RunStats],
                        spec: HardwareSpec, clock: _Clock
                        ) -> List[np.ndarray]:
         """Execute structurally-identical tile plans: per GEMM stage the
@@ -908,20 +1148,20 @@ class PallasBackend:
         interpret = self.resolved_interpret
 
         T = len(tiles)
-        wgroups0, shift = plans[0]
-        results_per_tile: List[List[Tuple[np.ndarray, np.ndarray]]] = \
+        wgroups0, shift = plans[0].wgroups, plans[0].shape.shift
+        results_per_tile: List[List[Tuple[int, np.ndarray]]] = \
             [[] for _ in range(T)]
 
         def rows_of(t: int, wi: int) -> np.ndarray:
             """Tile t's operand rows of GEMM stage wi."""
-            parts = plans[t][0][wi][1]
+            parts = plans[t].wgroups[wi][1]
             return parts[0][1] if len(parts) == 1 else \
-                np.concatenate([A for _, A in parts], axis=0)
+                np.concatenate([A for _, A, _ in parts], axis=0)
 
         for wi in range(len(wgroups0)):
             bm = bn = bk = 128
-            Ws = [wgroups[wi][0] for wgroups, _shift in plans]
-            Rg = sum(A.shape[0] for _, A in wgroups0[wi][1])
+            Ws = [plan.wgroups[wi][0] for plan in plans]
+            Rg = sum(A.shape[0] for _, A, _ in wgroups0[wi][1])
             K = wgroups0[wi][1][0][1].shape[1]
             Cg = Ws[0].shape[0]
             Rp = -(-Rg // bm) * bm
@@ -1007,21 +1247,17 @@ class PallasBackend:
             for t in range(T):
                 mat = mats[t]
                 off = 0
-                for g, A in plans[t][0][wi][1]:
+                for _, A, j in plans[t].wgroups[wi][1]:
                     rows = A.shape[0]
-                    results_per_tile[t].append((g, mat[off:off + rows]))
+                    results_per_tile[t].append((j, mat[off:off + rows]))
                     off += rows
 
         accs: List[np.ndarray] = []
         for t, tile in enumerate(tiles):
             results = results_per_tile[t]
-            g0, m0 = results[0]
-            if len(results) == 1 and g0.shape == tile.grid.shape \
-                    and (g0 == tile.grid).all():
-                acc = m0
-            else:
-                acc = self._scatter(results, tile.grid, spec)
-            accs.append(acc)
+            sh = plans[t].shape
+            accs.append(results[0][1] if sh.pos is None
+                        else self._scatter(results, tile.grid, sh, spec))
         if shift is None and tiles[0].alu_chain:
             accs = self._alu_chain_batch(accs, [t.alu_chain for t in tiles],
                                          clock)
@@ -1051,19 +1287,19 @@ class PallasBackend:
         out = self._alu_chain(x, chain, clock)
         return [out[t * R:(t + 1) * R] for t in range(T)]
 
-    def _scatter(self, results: Sequence[Tuple[np.ndarray, np.ndarray]],
-                 grid: np.ndarray, spec: HardwareSpec) -> np.ndarray:
-        """Accumulate per-group sub-grid results into a matrix in `grid`'s
-        orientation (uncovered reset-region elements stay zero)."""
+    def _scatter(self, results: Sequence[Tuple[int, np.ndarray]],
+                 grid: np.ndarray, shape: _TileShape,
+                 spec: HardwareSpec) -> np.ndarray:
+        """Accumulate per-group sub-grid results, ``(group, matrix)``,
+        into a matrix in `grid`'s orientation at the shape's scatter
+        positions (uncovered reset-region elements stay zero)."""
         io, ii = grid.shape
-        flat = grid.ravel()
-        order = np.argsort(flat)
         acc = np.zeros((grid.size, spec.batch, spec.block_out), np.int32)
-        for g, mat in results:
+        for j, mat in results:
+            g = shape.groups[j][0]
             blocked = self._from_matrix(mat, g.shape[0], g.shape[1], spec) \
                 .reshape(-1, spec.batch, spec.block_out)
-            pos = order[np.searchsorted(flat, g.ravel(), sorter=order)]
-            np.add.at(acc, pos, blocked)
+            np.add.at(acc, shape.pos[j], blocked)
         return self._to_matrix(
             acc.reshape(io, ii, spec.batch, spec.block_out), spec)
 

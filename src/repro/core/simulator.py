@@ -153,6 +153,10 @@ class RunStats:
     # means a long-lived multi-program server is cycling more distinct
     # streams than the cache holds
     decode_evictions: int = 0
+    # 1 when PallasBackend replayed the whole stream from the plan its
+    # first run recorded (decoded uops, index structure, tile
+    # bookkeeping), 0 when it analysed the stream or fell back part-way
+    plan_hit: int = 0
     # tuning-cache consultation of the compile that produced this
     # program (mirrored from CompiledProgram.tune_hits/tune_misses per
     # call): accel op nodes resolved from a TuningCache record vs ones
@@ -182,7 +186,7 @@ class RunStats:
                       "eager_alu_insns", "n_join_barriers",
                       "n_buffer_fences", "staging_bytes_per_call",
                       "tiles_resolved", "tile_batches", "lut_launches",
-                      "decode_evictions", "tune_cache_hits",
+                      "decode_evictions", "plan_hit", "tune_cache_hits",
                       "tune_cache_misses"):
                 setattr(out, f, getattr(out, f) + getattr(r, f))
             out.gang_size = max(out.gang_size, r.gang_size)

@@ -290,7 +290,7 @@ def test_decode_cache_is_a_bounded_lru_with_counted_evictions():
         # hit stream 0 to refresh its recency, then insert a 4th:
         # stream 1 (now the LRU) must be the one evicted
         hit, ev = eng._decode_cached(spec, isa, raw(0))
-        assert ev == 0 and hit == [("decoded", raw(0).tobytes())]
+        assert ev == 0 and hit.insns == [("decoded", raw(0).tobytes())]
         _, ev = eng._decode_cached(spec, isa, raw(3))
         assert ev == 1, "insert over cap must evict exactly one entry"
         _, ev = eng._decode_cached(spec, isa, raw(0))
