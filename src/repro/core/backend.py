@@ -112,10 +112,11 @@ _OUT_EDGES = DEP_OUT_EDGES
 _DECODE_CACHE: Dict[tuple, "_Decoded"] = {}
 _DECODE_LOCK = threading.Lock()
 # LRU bound on the shared cache: generous by default (a long-lived
-# multi-program server holds a handful of streams per program), but
+# multi-program server holds a handful of streams per program, and an
+# expert layer one per held expert and row count it has seen), but
 # configurable so it can never grow without limit.  Evictions are
 # counted — cumulatively here, per run in RunStats.decode_evictions.
-_DECODE_CACHE_CAP = 256
+_DECODE_CACHE_CAP = 1024
 _DECODE_EVICTIONS = 0
 
 

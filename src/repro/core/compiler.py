@@ -25,7 +25,14 @@ safe live here:
 
   * **segmentation**: ``cpu_only`` graph nodes split the stream into
     accelerator segments with host steps between them — real heterogeneous
-    execution, the Fig. 16 offload split executed rather than modelled.
+    execution, the Fig. 16 offload split executed rather than modelled;
+
+  * **streams emitted per call** (:class:`ExpertStep`): an expert layer's
+    GEMMs run over the rows a host step routed to each expert, a count
+    known only when the call runs.  The JIT emits the stream for each
+    (expert slot, padded rows) on first use into a :class:`JitWindow`
+    reserved in the program's image, caches it, and every later call
+    that observes the same count replays it.
 
 Every fence and barrier is also a **DRAM liveness point**: all earlier
 ops' loads are complete once it retires, so the program builder's arena
@@ -42,11 +49,14 @@ call N must still be there when call N+1's stream reads them.
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Sequence, Set, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Set, \
+    Tuple
 
 import numpy as np
 
+from .driver import Device, Dram
 from .isa import COMPUTE_Q, LOAD_Q
 from .runtime import Runtime
 from .scheduler import SramPartition
@@ -88,12 +98,137 @@ class AccelStep:
     n_fences: int = 0
     fence_edges: Tuple[Tuple[int, int], ...] = ()
     staged_addr: int = -1
+    # a stream the JIT emitted per call (ExpertStep) brings its own uop
+    # kernels: `image` (kernels, then the stream at staged_addr) is
+    # written at `image_addr`, inside the step's JitWindow, before it runs
+    image: Optional[np.ndarray] = None
+    image_addr: int = -1
 
 
 @dataclass
 class CpuStep:
     """One host-side op executed between accelerator segments."""
     node_id: int
+
+
+class _WindowDram(Dram):
+    """A Dram that holds only ``[lo, hi)``: the allocator the JIT's
+    Runtime stages uop kernels and the stream through when it emits a
+    variant into a :class:`JitWindow`."""
+
+    def __init__(self, lo: int, hi: int, align: int):
+        self.lo, self.size, self.align = lo, hi, align
+        self.mem = np.zeros(hi - lo, np.uint8)
+        self._next = lo
+        self._allocs: Dict[int, int] = {}
+
+    def write(self, addr: int, arr: np.ndarray) -> None:
+        b = np.ascontiguousarray(arr).view(np.uint8).ravel()
+        self.mem[addr - self.lo:addr - self.lo + b.size] = b
+
+    def read(self, addr: int, nbytes: int, dtype=np.uint8, shape=None):
+        out = self.mem[addr - self.lo:addr - self.lo + nbytes] \
+            .view(dtype).copy()
+        return out.reshape(shape) if shape is not None else out
+
+
+@dataclass(frozen=True)
+class JitWindow:
+    """DRAM ``[lo, lo + nbytes)`` reserved in a program's image at compile
+    time for the streams its JIT emits per call: every pool slot's clone
+    holds it at the same address, so a variant runs on any slot after its
+    ``image`` is written there (a plain write, never an allocation)."""
+    lo: int
+    nbytes: int
+    align: int
+
+    def emit(self, spec, lower: Callable[[Runtime], Tuple[int, ...]]
+             ) -> AccelStep:
+        """Lower one stream into the window: `lower(rt)` emits the op
+        (returning its node ids) through a fresh Runtime whose uop
+        kernels land in the window; the stream is staged after them."""
+        dev = Device(dram_size=0)
+        dev.dram = _WindowDram(self.lo, self.lo + self.nbytes, self.align)
+        rt = Runtime(spec, device=dev)
+        node_ids = lower(rt)
+        stream = rt.finalize_stream()
+        addr = dev.dram.alloc(stream.nbytes)
+        dev.dram.write(addr, stream)
+        return AccelStep(stream=stream, insn_count=stream.shape[0],
+                         n_barriers=0, n_drains=0, node_ids=node_ids,
+                         staged_addr=addr,
+                         image=dev.dram.mem[:dev.dram._next - self.lo].copy(),
+                         image_addr=self.lo)
+
+
+@dataclass(frozen=True)
+class ExpertLaunch:
+    """One expert slot's stream for one call: the slot, the live rows
+    routed to it (the whole slot for a shared expert), the rows launched
+    (live rounded up to the template's batch), whether the slot is a
+    routed expert, the most live rows any routed slot took in the call,
+    and the variant's stream."""
+    slot: int
+    rows: int
+    padded: int
+    routed: bool
+    load_max: int
+    step: AccelStep
+
+
+@dataclass
+class ExpertStep:
+    """An accelerator segment over the expert slots of an expert layer
+    (``Program.expert_matmul``), whose per-slot row counts a host step
+    writes into DRAM when the call runs.  Slots ``[0, routed)`` are
+    routed experts and run their live rows rounded up to the template's
+    batch, and no more; a routed slot with no rows launches nothing.  The
+    slots after them are shared experts and always run every row.
+
+    ``emit(slot, padded)`` lowers the stream of one (slot, padded rows)
+    variant into ``window``; :meth:`variant` emits each on first use and
+    caches it, so a call that observes a row count seen before replays
+    its stream."""
+    node_id: int
+    rows_id: int                 # node whose first `slots` int32s count rows
+    slots: int
+    routed: int
+    rows_max: int                # rows per slot in the slotted buffers
+    batch: int
+    window: JitWindow
+    emit: Callable[[int, int], AccelStep] = field(repr=False)
+    variants: Dict[Tuple[int, int], AccelStep] = field(default_factory=dict,
+                                                       repr=False)
+    _lock: Any = field(default_factory=threading.Lock, repr=False,
+                       compare=False)
+
+    def variant(self, slot: int, padded: int) -> AccelStep:
+        key = (slot, padded)
+        with self._lock:
+            got = self.variants.get(key)
+            if got is None:
+                got = self.variants[key] = self.emit(slot, padded)
+        return got
+
+    def launches(self, counts: np.ndarray) -> List[ExpertLaunch]:
+        """The streams one call runs, from its live row counts, in slot
+        order."""
+        counts = [int(c) for c in counts[:self.slots]]
+        if any(c < 0 or c > self.rows_max for c in counts):
+            raise ValueError(f"expert row counts {counts} outside "
+                             f"[0, {self.rows_max}]")
+        load_max = max(counts[:self.routed], default=0)
+        out = []
+        for s, c in enumerate(counts):
+            routed = s < self.routed
+            if not routed:
+                c = self.rows_max
+            if c == 0:
+                continue
+            padded = -(-c // self.batch) * self.batch
+            out.append(ExpertLaunch(s, c, padded, routed, load_max,
+                                    self.variant(s, padded)))
+        return out
 
 
 class ArenaAllocator:

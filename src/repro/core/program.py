@@ -49,8 +49,10 @@ Three DRAM liveness classes exist:
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
+import time
 import zlib
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -59,17 +61,19 @@ import numpy as np
 
 from . import hwspec as _hwspec, layout
 from .backend import BackendLike, resolve_backend
-from .compiler import (AccelStep, ArenaAllocator, CpuStep, ImageRange,
-                       SegmentBuilder)
+from .compiler import (AccelStep, ArenaAllocator, CpuStep, ExpertLaunch,
+                       ExpertStep, ImageRange, JitWindow, SegmentBuilder)
 from .conv import (ConvShape, conv1x1_eligible, conv2d_reference,
                    lower_conv1x1, lower_conv2d, lower_conv_im2col,
                    select_conv_lowering)
+from .driver import Device
 from .hwspec import HardwareSpec
 from .isa import AluOp, MemId
 from .runtime import Runtime
 from .scheduler import Epilogue, SramPartition, _ceil_div, lower_matmul, \
     lower_vector_binop
 from .simulator import RunStats
+from .spans import span
 
 # Counts every accelerator-segment build (scheduling + encoding).  Tests
 # assert it stays flat across repeated CompiledProgram calls and cached
@@ -229,6 +233,11 @@ class Node:
     # persistent nodes its fn mutates in place each call
     persistent: bool = False
     updates: Tuple[int, ...] = ()
+    span: Optional[str] = None     # host op: its span, ``vta.<span>``
+    # expert_matmul: its slots, and how many of them (the first) are
+    # routed experts
+    slots: int = 0
+    routed: int = 0
 
 
 def _epilogue_sig(ep: Optional[Epilogue]):
@@ -452,15 +461,73 @@ class Program:
     def add(self, a: TensorRef, b: TensorRef, **kw) -> TensorRef:
         return self.vector_binop(a, b, op=AluOp.ADD, **kw)
 
+    def expert_matmul(self, a: TensorRef, w: TensorRef, rows: TensorRef,
+                      slots: int, routed: int,
+                      epilogue: Optional[Epilogue] = None,
+                      name: Optional[str] = None) -> TensorRef:
+        """C = clip((A @ W^T) >> shift), slot by slot, over the expert
+        slots of an expert layer, each over only the rows routed to it.
+
+        `a` is (slots*M, K): slot s owns rows [s*M, (s+1)*M).  `w` is
+        (slots*N, K): slot s's weights are rows [s*N, (s+1)*N).  `rows`
+        is an int32 vector a host op writes when the call runs; its first
+        `slots` entries count the live rows at the head of each routed
+        slot.  The result is (slots*M, N), slot s at rows [s*M, ...).
+        Slots [0, routed) are routed experts: each runs its live rows
+        rounded up to the template's batch, and a slot with none launches
+        nothing.  The slots after them are shared experts and run all M
+        rows.  Rows past what a slot ran keep whatever they held.
+
+        The stream of each (slot, padded rows) is emitted by the JIT the
+        first time a call needs it and replayed by every later call that
+        needs it (:class:`compiler.ExpertStep`)."""
+        spec = self.spec
+        SM, K = self._node(a).shape
+        SN, K2 = self._node(w).shape
+        if K != K2:
+            raise ValueError(f"expert_matmul K mismatch: {K} vs {K2}")
+        if slots < 1 or not 0 <= routed <= slots or SM % slots \
+                or SN % slots:
+            raise ValueError(f"expert_matmul: {SM} rows and {SN} weight "
+                             f"rows do not split into {slots} slots "
+                             f"({routed} routed)")
+        M, N = SM // slots, SN // slots
+        if M % spec.batch or N % spec.block_out:
+            raise ValueError(f"expert_matmul: rows per slot ({M}) must be "
+                             f"a multiple of batch {spec.batch} and "
+                             f"outputs per slot ({N}) of block_out "
+                             f"{spec.block_out}")
+        if epilogue is not None and epilogue.bias_blocked is not None:
+            raise ValueError("expert_matmul takes no bias")
+        (n_rows,) = self._node(rows).shape
+        if n_rows < slots:
+            raise ValueError(f"row counts hold {n_rows} < {slots} slots")
+        self._require(a, TensorMeta("mat", (SM, K), "int8", spec.block_in),
+                      "expert_matmul A")
+        self._require(w, TensorMeta("wgt", (SN, K), "int8"),
+                      "expert_matmul W")
+        self._require(rows, TensorMeta("vec", (n_rows,), "int32",
+                                       spec.block_out), "expert rows")
+        idx = len(self.nodes)
+        return self._add(Node(
+            idx=idx, op="expert_matmul", name=name or f"experts{idx}",
+            inputs=(a.idx, w.idx, rows.idx), shape=(SM, N),
+            meta=TensorMeta("mat", (SM, N), "int8", spec.block_out),
+            epilogue=epilogue, slots=slots, routed=routed))
+
     def host(self, fn: Callable, *args: TensorRef,
              shape: Sequence[int], kind: str = "conv", dtype: str = "int8",
              name: Optional[str] = None, key: Optional[str] = None,
-             updates: Sequence[TensorRef] = ()) -> TensorRef:
+             updates: Sequence[TensorRef] = (),
+             span: Optional[str] = None) -> TensorRef:
         """Arbitrary host-side op on logical numpy arrays; splits the
         stream into accelerator segments around it.  Inputs must already
         have a bound layout (consume them with a typed op first, or use
         typed inputs).  Programs containing keyless host fns are not
-        eligible for the compile cache.
+        eligible for the compile cache.  With `span` the step, from
+        reading its inputs to writing its output, runs inside the host
+        span ``vta.<span>`` and its seconds land on the request's
+        ``RunStats.host_span_s`` under that name.
 
         ``updates`` names persistent buffers this op mutates: the fn must
         then return ``(out, new_value, ...)`` — one extra array per
@@ -486,7 +553,8 @@ class Program:
             idx=idx, op="cpu", name=name or f"host{idx}",
             inputs=tuple(r.idx for r in args), shape=tuple(shape),
             meta=TensorMeta(kind, tuple(shape), dtype, block),
-            fn=fn, fn_key=key, updates=tuple(r.idx for r in updates)))
+            fn=fn, fn_key=key, updates=tuple(r.idx for r in updates),
+            span=span))
 
     def output(self, ref: TensorRef) -> TensorRef:
         self._node(ref)
@@ -511,7 +579,8 @@ class Program:
             rows.append((n.op, n.name, n.inputs, n.shape,
                          n.meta, _epilogue_sig(n.epilogue), n.conv,
                          n.alu_op, n.lowering, n.fn_key, const_sig,
-                         n.persistent, n.updates))
+                         n.persistent, n.updates, n.span, n.slots,
+                         n.routed))
         return (self.spec, self.virtual_threads, tuple(rows),
                 tuple(self._outputs))
 
@@ -573,7 +642,7 @@ def compile_multi(progs: Sequence[Program], fence_mode: str = "buffer",
     if not progs:
         raise ValueError("compile_multi of zero programs")
     out: List[CompiledProgram] = []
-    device = None
+    device = Device(dram_size=_dram_bytes(progs))
     for p in progs:
         c = _build(p, fence_mode=fence_mode, prestage=prestage,
                    device=device)
@@ -583,6 +652,16 @@ def compile_multi(progs: Sequence[Program], fence_mode: str = "buffer",
         assert not a.image_range.overlaps(b.image_range), \
             "co-staged programs overlap in DRAM — allocator invariant broken"
     return out
+
+
+def _dram_bytes(progs: Sequence[Program]) -> int:
+    """Address space for a device that stages `progs`: the default 256
+    MiB, or twice every tensor they declare (streams, uop kernels, the
+    arena's slack and JIT windows fit in the other half) where that is
+    more.  Unused space costs nothing until a page is written."""
+    need = sum(n.meta.nbytes(p.spec) for p in progs for n in p.nodes
+               if n.meta is not None)
+    return max(1 << 28, 2 * need + (1 << 24))
 
 
 # ----------------------------------------------------------------------
@@ -675,6 +754,8 @@ def _build(prog: Program, fence_mode: str = "buffer",
     # lowerings are fixed modes by now, and the CompiledProgram carries
     # these copies so describe() shows what was actually lowered
     pnodes = list(tuned.nodes)
+    if device is None:
+        device = Device(dram_size=_dram_bytes([prog]))
     rt = Runtime(spec, device=device)
     image_lo = rt.device.dram._next
     addrs: Dict[int, int] = {}
@@ -755,7 +836,7 @@ def _build(prog: Program, fence_mode: str = "buffer",
     next_in_segment: Dict[int, Node] = {}
     prev_accel: Optional[Node] = None
     for n in pnodes:
-        if n.op == "cpu":
+        if n.op in ("cpu", "expert_matmul"):
             prev_accel = None
         elif n.op in ("matmul", "conv2d", "vbinop"):
             if prev_accel is not None:
@@ -800,20 +881,52 @@ def _build(prog: Program, fence_mode: str = "buffer",
             return lower
         raise ValueError(n.op)
 
-    steps: List[Union[AccelStep, CpuStep]] = []
+    def expert_step(n: Node) -> ExpertStep:
+        """The per-call segment of an expert_matmul node, with a JIT
+        window sized from its largest variant (every slot's stream has
+        the same shape; only base addresses differ)."""
+        a, w = (pnodes[i] for i in n.inputs[:2])
+        M = a.shape[0] // n.slots
+        Kb = _ceil_div(a.shape[1], spec.block_in)
+        Nb = _ceil_div(w.shape[0] // n.slots, spec.block_out)
+        Mb = M // spec.batch
+
+        def lowering(slot: int, padded: int):
+            def lower(r: Runtime):
+                lower_matmul(r, a_base=elem(a.idx) + slot * Mb * Kb,
+                             w_base=elem(w.idx) + slot * Nb * Kb,
+                             c_base=elem(n.idx) + slot * Mb * Nb,
+                             Mb=padded // spec.batch, Nb=Nb, Kb=Kb,
+                             epilogue=n.epilogue, virtual_threads=vt)
+                return (n.idx,)
+            return lower
+
+        page = 4096
+        probe = JitWindow(page, 1 << 26, 64).emit(spec, lowering(0, M))
+        nbytes = _ceil_div(2 * probe.image.nbytes + page, page) * page
+        window = JitWindow(rt.buffer_alloc(nbytes, align=page), nbytes, 64)
+        return ExpertStep(node_id=n.idx, rows_id=n.inputs[2],
+                          slots=n.slots, routed=n.routed, rows_max=M,
+                          batch=spec.batch, window=window,
+                          emit=lambda s, p: window.emit(spec,
+                                                        lowering(s, p)))
+
+    steps: List[Union[AccelStep, CpuStep, ExpertStep]] = []
     seg = SegmentBuilder(rt, fence_mode=fence_mode)
     for n in pnodes:
         if n.op == "input":
             continue
-        if n.op == "cpu":
+        if n.op in ("cpu", "expert_matmul"):
             step = seg.finish()
             if step is not None:
                 steps.append(step)
                 STREAM_BUILDS += 1
             # the previous segment fully retires before the host step
-            # runs, so this is a DRAM liveness point too
+            # or the expert segment runs, so this is a DRAM liveness
+            # point too
             alloc_node(n, sync=True)
-            steps.append(CpuStep(node_id=n.idx))
+            steps.append(CpuStep(node_id=n.idx) if n.op == "cpu"
+                         else expert_step(n))
             continue
         nxt = next_in_segment.get(n.idx)
         reads = {addrs[i] for i in n.inputs if i in op_outputs}
@@ -871,6 +984,9 @@ def _build(prog: Program, fence_mode: str = "buffer",
 # ----------------------------------------------------------------------
 # the compiled artifact
 # ----------------------------------------------------------------------
+_NO_SPAN = contextlib.nullcontext()
+
+
 @dataclass
 class RunResult:
     """One execution of a CompiledProgram against SOME device: outputs,
@@ -898,7 +1014,7 @@ class CompiledProgram:
     spec: HardwareSpec
     nodes: List[Node]
     addrs: Dict[int, int]
-    steps: List[Union[AccelStep, CpuStep]]
+    steps: List[Union[AccelStep, CpuStep, ExpertStep]]
     input_ids: Dict[str, int]
     output_ids: List[int]
     device: Any
@@ -947,6 +1063,10 @@ class CompiledProgram:
         return [s for s in self.steps if isinstance(s, CpuStep)]
 
     @property
+    def expert_steps(self) -> List[ExpertStep]:
+        return [s for s in self.steps if isinstance(s, ExpertStep)]
+
+    @property
     def insn_count(self) -> int:
         return sum(s.insn_count for s in self.accel_steps)
 
@@ -990,6 +1110,10 @@ class CompiledProgram:
                 parts.append(f"accel[{names}: {s.insn_count} insns, "
                              f"{s.n_barriers} barriers, "
                              f"{s.n_fences} fences{edges}]")
+            elif isinstance(s, ExpertStep):
+                parts.append(f"experts[{self.nodes[s.node_id].name}: "
+                             f"{s.slots} slots ({s.routed} routed), "
+                             f"{len(s.variants)} streams emitted]")
             else:
                 parts.append(f"cpu[{self.nodes[s.node_id].name}]")
         chain = " -> ".join(parts)
@@ -1147,15 +1271,23 @@ class CompiledProgram:
                    for name, arr in inputs.items())
 
     def exec_step(self, step: Union[AccelStep, CpuStep], device: Any,
-                  eng: Any, timing: Any = None) -> Optional[RunStats]:
+                  eng: Any, timing: Any = None,
+                  host_s: Optional[Dict[str, float]] = None
+                  ) -> Optional[RunStats]:
         """Run ONE step of the program against `device`: accelerator
         segments hand the encoded stream to `eng` (kicking the pre-staged
         copy when available), host steps run the node's fn on logical
         arrays read from/written to the same device.  Returns the
         segment's RunStats (None for host steps).  Touches no shared
         mutable state — the pool scheduler interleaves steps of different
-        requests through this hook."""
+        requests through this hook.  An :class:`ExpertStep` runs as the
+        streams :meth:`expert_launches` picks for the call, each passed
+        here as an AccelStep.  A named host step's seconds, timed over
+        its span, are added to `host_s` under the span's name."""
+        if isinstance(step, ExpertStep):
+            raise TypeError("run an ExpertStep through expert_launches")
         if isinstance(step, AccelStep):
+            self.load_image(step, device)
             if self.prestage and step.staged_addr >= 0:
                 stats = eng.execute(self.spec, device, step.stream,
                                     timing=timing,
@@ -1170,19 +1302,50 @@ class CompiledProgram:
             stats.tune_cache_misses = self.tune_misses
             return stats
         node = self.nodes[step.node_id]
-        args = [self._read(i, device=device) for i in node.inputs]
-        res = node.fn(*args)
-        if node.updates:
-            # fn returned (out, new_state, ...): write each new state
-            # image back into its persistent buffer IN PLACE — same
-            # stable address every call, never an allocation
-            out, *new_state = res
-            for nid, arr in zip(node.updates, new_state):
-                self._write(nid, arr, device=device)
-        else:
-            out = res
-        self._write(step.node_id, out, device=device)
+        t = time.perf_counter()
+        with span(node.span) if node.span else _NO_SPAN:
+            args = [self._read(i, device=device) for i in node.inputs]
+            res = node.fn(*args)
+            if node.updates:
+                # fn returned (out, new_state, ...): write each new state
+                # image back into its persistent buffer IN PLACE — same
+                # stable address every call, never an allocation
+                out, *new_state = res
+                for nid, arr in zip(node.updates, new_state):
+                    self._write(nid, arr, device=device)
+            else:
+                out = res
+            self._write(step.node_id, out, device=device)
+        if node.span and host_s is not None:
+            host_s[node.span] = (host_s.get(node.span, 0.0)
+                                 + time.perf_counter() - t)
         return None
+
+    @staticmethod
+    def load_image(step: AccelStep, device: Any) -> None:
+        """Write a JIT-emitted stream's image (its uop kernels and the
+        stream) into its window on `device`; a no-op for static
+        steps."""
+        if step.image is not None:
+            device.dram.write(step.image_addr, step.image)
+            device.flush_cache(step.image_addr, step.image.nbytes)
+
+    def expert_launches(self, step: ExpertStep,
+                        device: Any) -> List[ExpertLaunch]:
+        """The streams `step` runs for the call staged on `device`, from
+        the row counts its host step wrote there; the JIT emits any not
+        seen before."""
+        return step.launches(self._read(step.rows_id, device=device))
+
+    @staticmethod
+    def note_launch(stats: RunStats, launch: ExpertLaunch) -> None:
+        """Put one expert launch's row counters on its segment's stats
+        (routed experts only)."""
+        if launch.routed:
+            stats.moe_rows = launch.rows
+            stats.moe_rows_padded = launch.padded
+            stats.moe_experts_hit = 1
+            stats.moe_load_max = launch.load_max
 
     def read_outputs(self, device: Any = None
                      ) -> Union[np.ndarray, Dict[str, np.ndarray]]:
@@ -1202,12 +1365,24 @@ class CompiledProgram:
         staging = self.stage_inputs(dict(inputs or {}), device=device)
         eng = resolve_backend(backend)
         stats_list: List[RunStats] = []
+        host_s: Dict[str, float] = {}
         for step in self.steps:
-            stats = self.exec_step(step, device, eng, timing=timing)
-            if stats is not None:
-                if not (self.prestage and step.staged_addr >= 0):
-                    staging += step.stream.nbytes  # re-staged every call
+            if isinstance(step, CpuStep):
+                self.exec_step(step, device, eng, timing=timing,
+                               host_s=host_s)
+                continue
+            runs = ([(step, None)] if isinstance(step, AccelStep) else
+                    [(ln.step, ln) for ln in
+                     self.expert_launches(step, device)])
+            for st, ln in runs:
+                stats = self.exec_step(st, device, eng, timing=timing)
+                if ln is not None:
+                    self.note_launch(stats, ln)
+                if not (self.prestage and st.staged_addr >= 0):
+                    staging += st.stream.nbytes  # re-staged every call
                 stats_list.append(stats)
+        if stats_list:
+            stats_list[-1].host_span_s = host_s
         for s in stats_list:
             s.staging_bytes_per_call = staging
         return RunResult(outputs=self.read_outputs(device=device),

@@ -43,8 +43,14 @@ makes them form on purpose:
 Determinism contract: admission changes WHEN a request runs, never what
 it computes — every released request executes the same pre-staged stream
 on its own slot device, so results are byte-identical to serial
-execution.  The fuzzer's ``sched`` flavor byte-diffs random graphs
-through randomized window/backpressure configs against serial runs.
+execution.  An expert layer's requests (``Program.expert_matmul``) are
+released together like any other, but each runs the streams its own
+routing picked: the pool gangs them per stream, only where they need the
+same (expert, padded rows) stream, and pads no request to a peer's rows.
+The gang-width tuner prices the static segments only; an expert
+segment's streams are not known before the calls run.  The fuzzer's
+``sched`` flavor byte-diffs random graphs through randomized
+window/backpressure configs against serial runs.
 """
 from __future__ import annotations
 

@@ -32,6 +32,11 @@ submit()/wait() API.
     gang (their streams differ); the continuous-batching admission
     layer (``core.sched``) exists to park and release same-program
     requests together so gangs actually form under open-loop traffic.
+    At an expert segment (:class:`compiler.ExpertStep`) each request
+    needs the streams of its own routing, one per held expert with
+    rows: requests gang per stream, only where they need the very same
+    (expert, padded rows) stream, and are never padded to a peer's row
+    count.
 
   * :class:`BatchServer` — shards a batch of requests across the pool
     and gathers results in submission order.
@@ -107,7 +112,7 @@ import numpy as np
 
 from .backend import BackendLike, resolve_backend
 from .chaos import FaultPlan
-from .compiler import AccelStep, CpuStep
+from .compiler import AccelStep, CpuStep, ExpertLaunch, ExpertStep
 from .isa import IsaLayout
 from .program import CompiledProgram
 from .simulator import RunStats, TimingModel, replay_timing
@@ -194,6 +199,9 @@ class PoolFuture:
         # segment has been waiting (the enqueue, then each segment's end)
         self.park_s = 0.0
         self.queued_at = time.perf_counter()
+        # the call's named host steps' seconds, by span name; put on
+        # its last segment's RunStats when it retires
+        self.host_span_s: Dict[str, float] = {}
         self._done = threading.Event()
         self._outputs: Any = None
         self._exc: Optional[BaseException] = None
@@ -522,7 +530,7 @@ class DevicePool:
         self._round_watch: set = set()      # slot ids still owing work
         self._round_had_host = False
         self._round_abandoned = -1          # last round the watchdog shot
-        self._budget_cache: Dict[Tuple[int, int], float] = {}
+        self._budget_cache: Dict[Tuple[int, ...], float] = {}
         # persistent host worker: one long-lived thread consuming host
         # segment batches, so the hot serving path never pays per-round
         # thread creation
@@ -1013,20 +1021,24 @@ class DevicePool:
     # segment watchdog
     # ------------------------------------------------------------------
     def _accel_step_seconds(self, prog: CompiledProgram, pk: int,
-                            idx: int) -> float:
+                            idx: int, step: Optional[AccelStep] = None
+                            ) -> float:
         """Predicted wall seconds of one accelerator segment: decode the
         stream, replay it on the TimingModel, convert cycles at the
         PROGRAM's spec frequency — replayed cycles are in the spec's
         clock domain, so any other rate is off by the frequency ratio
         (a re-fitted/calibrated spec would get spuriously tight or
         never-firing deadlines).  The interpret-mode slowdown is what
-        ``WatchdogConfig.mult``/``floor_s`` pad for.  Cached per
-        (program, step): decode + replay run once per pool lifetime."""
-        key = (pk, idx)
+        ``WatchdogConfig.mult``/``floor_s`` pad for.  `step` is the
+        stream an expert step picked (default: the program's step
+        `idx`).  Cached per (program, step, stream): decode + replay run
+        once per pool lifetime."""
+        key = (pk, idx) if step is None else (pk, idx, id(step))
         got = self._budget_cache.get(key)
         if got is not None:
             return got
-        step = prog.steps[idx]
+        if step is None:
+            step = prog.steps[idx]
         tm = (self.timing if isinstance(self.timing, TimingModel)
               else TimingModel(prog.spec))
         insns = IsaLayout(prog.spec).decode_stream(
@@ -1197,8 +1209,10 @@ class DevicePool:
                             continue              # killed/retried
                         step = req.prog.steps[step_idx]
                         try:
-                            req.prog.exec_step(step, device, self.engine,
-                                               timing=self.timing)
+                            req.prog.exec_step(
+                                step, device, self.engine,
+                                timing=self.timing,
+                                host_s=req.future.host_span_s)
                             req.future.queued_at = time.perf_counter()
                             slot.stats.cpu_steps += 1
                         except BaseException as e:
@@ -1330,18 +1344,33 @@ class DevicePool:
 
         # accelerator work grouped up front: SAME-PROGRAM same-step
         # requests gang (streams must be identical for lockstep
-        # execution; different programs never gang)
+        # execution; different programs never gang).  At an expert step
+        # each request runs the streams its routing picked, and requests
+        # gang per stream: key (program, step, slot, padded rows)
         host_slots: List[_Slot] = []
-        by_key: Dict[Tuple[int, int], Tuple[CompiledProgram,
-                                            List[_Slot]]] = {}
+        by_key: Dict[Tuple, Tuple[CompiledProgram, AccelStep,
+                                  List[_Slot]]] = {}
+        launches: Dict[Tuple, Dict[int, ExpertLaunch]] = {}
         for slot in active:
             st = step_of(slot)
             if isinstance(st, CpuStep):
                 host_slots.append(slot)
-            elif isinstance(st, AccelStep):
-                req = slot.active
-                key = (self._prog_key[id(req.prog)], req.step_idx)
-                by_key.setdefault(key, (req.prog, []))[1].append(slot)
+                continue
+            req = slot.active
+            key = (self._prog_key[id(req.prog)], req.step_idx)
+            if isinstance(st, AccelStep):
+                by_key.setdefault(key, (req.prog, st, []))[2].append(slot)
+            elif isinstance(st, ExpertStep):
+                try:
+                    mine = req.prog.expert_launches(st, slot.device)
+                except BaseException as e:
+                    self._retire(slot, error=e)
+                    continue
+                for ln in mine:
+                    k = key + (ln.slot, ln.padded)
+                    by_key.setdefault(k, (req.prog, ln.step, []))[2] \
+                        .append(slot)
+                    launches.setdefault(k, {})[slot.id] = ln
 
         # arm the segment watchdog: the round's budget sums the
         # TimingModel-predicted wall time of its DISTINCT accel segments
@@ -1351,14 +1380,14 @@ class DevicePool:
         rid = 0
         if self.watchdog is not None:
             budget = self.watchdog.floor_s
-            for (pk, idx), (prog, _) in by_key.items():
-                budget += self.watchdog.mult * \
-                    self._accel_step_seconds(prog, pk, idx)
+            for key, (prog, step, _) in by_key.items():
+                budget += self.watchdog.mult * self._accel_step_seconds(
+                    prog, key[0], key[1], step if len(key) > 2 else None)
             with self._lock:
                 self._round_id += 1
                 rid = self._round_id
                 self._round_watch = {s.id for s in host_slots} | {
-                    s.id for _, grp in by_key.values() for s in grp}
+                    s.id for _, _, grp in by_key.values() for s in grp}
                 self._round_had_host = bool(host_slots)
                 self._round_deadline = time.perf_counter() + budget
 
@@ -1383,9 +1412,18 @@ class DevicePool:
 
         accel_errs: Dict[int, BaseException] = {}
         try:
-            for (_, idx), (prog, group) in by_key.items():
+            for key, (prog, step, group) in by_key.items():
+                if any(s.id in accel_errs for s in group):
+                    # a request whose earlier expert stream failed this
+                    # round runs none of its others
+                    group = [s for s in group if s.id not in accel_errs]
+                    if not group:
+                        continue
                 try:
-                    self._exec_accel(prog, prog.steps[idx], group)
+                    if key in launches:
+                        self._exec_accel(prog, step, group, launches[key])
+                    else:
+                        self._exec_accel(prog, step, group)
                 except BaseException as e:
                     # fail ONLY the gang that raised; other requests in
                     # this round proceed untouched
@@ -1435,14 +1473,19 @@ class DevicePool:
                 self._retire(slot)
 
     def _exec_accel(self, prog: CompiledProgram, step: AccelStep,
-                    group: List[_Slot]) -> None:
+                    group: List[_Slot],
+                    launches: Optional[Dict[int, ExpertLaunch]] = None
+                    ) -> None:
         """Run one accelerator segment for every slot in `group` — as a
         lockstep gang when the engine supports it (identical pre-staged
         stream on every slot), serially otherwise.  This is the pool's
         gang clock: scripted chaos faults fire here, integrity checks
         run before the gang touches DRAM, and the executing set is
         filtered + device-captured under the pool lock so a slot killed
-        or respawned mid-round is never scribbled on."""
+        or respawned mid-round is never scribbled on.  `launches` gives,
+        by slot id, the expert launch each slot runs `step` for: a
+        JIT-emitted stream's image is written into each slot's window
+        first, and the launch's row counters land on its RunStats."""
         gang_idx = next(self._gang_seq)
         if self.fault_plan is not None:
             self._apply_faults(gang_idx, prog, group)
@@ -1459,6 +1502,8 @@ class DevicePool:
         pk = self._prog_key[id(prog)]
         if gang is not None and len(trios) > 1 and prestaged:
             start = time.perf_counter()
+            for _, d, _ in trios:
+                prog.load_image(step, d)
             with tagged(prog=pk, seq0=trios[0][2].future.seq):
                 statss = gang(prog.spec, [d for _, d, _ in trios],
                               step.stream, timing=self.timing,
@@ -1468,6 +1513,8 @@ class DevicePool:
                 stats.n_join_barriers = step.n_barriers
                 stats.n_buffer_fences = step.n_fences
                 stats.staging_bytes_per_call = req.future.staging_bytes
+                if launches:
+                    prog.note_launch(stats, launches[slot.id])
                 self._record_waits(req.future, stats, start, end)
                 req.future.stats.append(stats)
                 slot.stats.accel_steps += 1
@@ -1483,6 +1530,8 @@ class DevicePool:
                                        timing=self.timing)
             end = time.perf_counter()
             stats.staging_bytes_per_call = req.future.staging_bytes
+            if launches:
+                prog.note_launch(stats, launches[slot.id])
             self._record_waits(req.future, stats, start, end)
             req.future.stats.append(stats)
             slot.stats.accel_steps += 1
@@ -1571,6 +1620,9 @@ class DevicePool:
         else:
             try:
                 outs = req.prog.read_outputs(device=slot.device)
+                fut = req.future
+                if fut.stats:
+                    fut.stats[-1].host_span_s = fut.host_span_s
                 slot.stats.calls += 1
                 # checkpoint BEFORE resolving the future: once wait()
                 # returns under checkpoint_every=1 the call is durable —

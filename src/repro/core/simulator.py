@@ -157,6 +157,18 @@ class RunStats:
     # first run recorded (decoded uops, index structure, tile
     # bookkeeping), 0 when it analysed the stream or fell back part-way
     plan_hit: int = 0
+    # expert layers (Program.expert_matmul), on the segment of each
+    # routed expert's stream: the call's live rows routed to that expert,
+    # the rows launched for it (live rounded up to the template's batch),
+    # 1, and the most live rows any held routed expert took in the call;
+    # 0 on every other segment
+    moe_rows: int = 0
+    moe_rows_padded: int = 0
+    moe_experts_hit: int = 0
+    moe_load_max: int = 0
+    # host seconds, by span name, of the call's named host steps
+    # (Program.host(span=...)), on the call's last accelerator segment
+    host_span_s: Dict[str, float] = field(default_factory=dict)
     # tuning-cache consultation of the compile that produced this
     # program (mirrored from CompiledProgram.tune_hits/tune_misses per
     # call): accel op nodes resolved from a TuningCache record vs ones
@@ -175,7 +187,7 @@ class RunStats:
         """Sum the counter fields of several runs (e.g. one pooled slot's
         serving history) into one aggregate RunStats.  Cycle/wall fields
         add too — meaningful as totals, not as a single-run profile;
-        ``gang_size`` reports the maximum seen."""
+        ``gang_size`` and ``moe_load_max`` report the maximum seen."""
         out = cls(modules={})
         for r in runs:
             for f in ("total_cycles", "gemm_macs", "alu_ops",
@@ -186,10 +198,14 @@ class RunStats:
                       "eager_alu_insns", "n_join_barriers",
                       "n_buffer_fences", "staging_bytes_per_call",
                       "tiles_resolved", "tile_batches", "lut_launches",
-                      "decode_evictions", "plan_hit", "tune_cache_hits",
-                      "tune_cache_misses"):
+                      "decode_evictions", "plan_hit", "moe_rows",
+                      "moe_rows_padded", "moe_experts_hit",
+                      "tune_cache_hits", "tune_cache_misses"):
                 setattr(out, f, getattr(out, f) + getattr(r, f))
             out.gang_size = max(out.gang_size, r.gang_size)
+            out.moe_load_max = max(out.moe_load_max, r.moe_load_max)
+            for nm, sec in r.host_span_s.items():
+                out.host_span_s[nm] = out.host_span_s.get(nm, 0.0) + sec
             for nm, ms in r.modules.items():
                 agg = out.modules.setdefault(nm, ModuleStats())
                 agg.busy_cycles += ms.busy_cycles

@@ -112,3 +112,20 @@ def test_decode_attention_compiles(one_chip):
                          _sds(one_chip, (H, S, D), jnp.float32),
                          _sds(one_chip, (1,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,k,n", [(128, 2048, 1536), (256, 2048, 1536),
+                                      (128, 768, 2048), (256, 768, 2048),
+                                      (256, 2048, 256)],
+                         ids=["gate_up", "gate_up_256", "down", "down_256",
+                              "router"])
+def test_vta_gemm_compiles_at_moe_widths(one_chip, rows, k, n):
+    """The launches of JoyAI-LLM-Flash's MoE layer on tpu_like(): an
+    expert's gate|up (2048 -> 2 x 768) and down (768 -> 2048) with its
+    rows padded to 128, or 256 for the shared expert's 256 tokens and for
+    gangs past 128 rows, and the router (2048 -> 256 experts)."""
+    fn = functools.partial(vta_gemm_pallas, epilogue="requant", shift=13,
+                           interpret=False)
+    text = _compile_text(fn, _sds(one_chip, (rows, k), jnp.int8),
+                         _sds(one_chip, (k, n), jnp.int8))
+    assert "tpu_custom_call" in text
