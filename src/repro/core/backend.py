@@ -146,6 +146,58 @@ def decode_cache_info() -> Dict[str, int]:
                 "evictions": _DECODE_EVICTIONS}
 
 
+# content-addressed, device-resident cache of padded GEMM weight
+# operands (see _wgt_operand): a constant weight tile is padded,
+# transposed and uploaded once, and every later launch that needs the
+# same bytes takes the device array.  Keyed by the tile's exact content,
+# so weights that differ never share an entry.  Shared across backend
+# instances and serving threads under _WGT_LOCK; LRU order, bounded by
+# the padded device bytes it holds (the host keys are no larger), with
+# evictions counted.
+_WGT_CACHE: Dict[tuple, object] = {}
+_WGT_LOCK = threading.Lock()
+_WGT_CACHE_CAP_BYTES = 512 << 20
+_WGT_CACHE_BYTES = 0
+_WGT_EVICTIONS = 0
+
+
+def wgt_cache_info() -> Dict[str, int]:
+    """Live entries / device bytes / byte bound / lifetime eviction count
+    of the shared weight-operand cache (ops introspection)."""
+    with _WGT_LOCK:
+        return {"size": len(_WGT_CACHE), "bytes": _WGT_CACHE_BYTES,
+                "cap_bytes": _WGT_CACHE_CAP_BYTES,
+                "evictions": _WGT_EVICTIONS}
+
+
+def _wgt_operand(key: tuple, W: np.ndarray, Kp: int, Cp: int
+                 ) -> Tuple[object, bool]:
+    """The (Kp, Cp) int8 device operand of the weight tile `W` ((C, K),
+    transposed and zero-padded; `Kp`, `Cp` follow from its shape), and
+    whether the cache held it.  `key` is ``(W.shape, W.tobytes())``.
+    A miss builds and uploads the operand outside the lock and keeps
+    it, evicting the least-recently-used entries to stay in the bound."""
+    import jax.numpy as jnp
+    global _WGT_CACHE_BYTES, _WGT_EVICTIONS
+    with _WGT_LOCK:
+        hit = _WGT_CACHE.pop(key, None)
+        if hit is not None:
+            _WGT_CACHE[key] = hit      # re-insert: LRU order by last hit
+            return hit, True
+    Wp = np.zeros((Kp, Cp), np.int8)
+    Wp[:W.shape[1], :W.shape[0]] = W.T
+    dev = jnp.asarray(Wp)
+    with _WGT_LOCK:
+        if key not in _WGT_CACHE and Wp.nbytes <= _WGT_CACHE_CAP_BYTES:
+            while _WGT_CACHE_BYTES + Wp.nbytes > _WGT_CACHE_CAP_BYTES:
+                _WGT_CACHE_BYTES -= _WGT_CACHE.pop(
+                    next(iter(_WGT_CACHE))).nbytes
+                _WGT_EVICTIONS += 1
+            _WGT_CACHE[key] = dev
+            _WGT_CACHE_BYTES += Wp.nbytes
+    return dev, False
+
+
 @dataclass
 class _Decoded:
     """One decoded stream as the cache holds it: its instructions and,
@@ -210,8 +262,10 @@ class _TileLayout:
 @dataclass
 class _TilePlan:
     """One tile's resolution plan for one run (``_plan_tile``): GEMM
-    stages ``wgroups = [(W, [(grid, A, group), ...]), ...]``, one per
-    distinct weight tile, and which grid groups each stage holds."""
+    stages ``wgroups = [(W, [(grid, A, group), ...], key), ...]``, one
+    per distinct weight tile (`key` is ``(W.shape, W.tobytes())``, the
+    tile's content, which also keys the weight-operand cache), and which
+    grid groups each stage holds."""
     wgroups: list
     shape: _TileShape
     partition: Tuple[Tuple[int, ...], ...]
@@ -876,12 +930,14 @@ class PallasBackend:
             big = dst_mats[0] if len(states) == 1 \
                 else np.concatenate(dst_mats, axis=0)
             args = [jnp.asarray(big)]
+            up = big.nbytes
             if not insn.use_imm:
                 src_mats = [self._to_matrix(st.sim.acc_sram[src_grid], s)
                             for st in states]
                 big_src = src_mats[0] if len(states) == 1 \
                     else np.concatenate(src_mats, axis=0)
                 args.append(jnp.asarray(big_src))
+                up += big_src.nbytes
         chain = ((op, int(insn.imm)),) if insn.use_imm else ((op, None),)
         with clock.phase("launch"):
             out = tensor_alu(*args, chain=chain, use_pallas=True,
@@ -896,6 +952,7 @@ class PallasBackend:
             sim.out_sram[touched] = sim.acc_sram[touched].astype(np.int8)
             stats.alu_ops += ops
             stats.coalesced_alu_insns += 1
+            stats.upload_bytes += up
 
     # ------------------------------------------------------------------
     # pending-tile resolution
@@ -986,7 +1043,7 @@ class PallasBackend:
                                       st.clock)[0]
         elif tile.alu_chain:
             acc = self._alu_chain(np.zeros((R, C), np.int32), tile.alu_chain,
-                                  st.clock)
+                                  st.clock, [stats])
         else:
             acc = np.zeros((R, C), np.int32)
         self._writeback(st, tile, acc, stats)
@@ -1090,10 +1147,11 @@ class PallasBackend:
             if w is None:
                 W = ch[idx[0]].w if len(idx) == 1 else \
                     np.concatenate([ch[i].w for i in idx], axis=1)
-                w = windex.setdefault((W.shape, W.tobytes()), len(wgroups))
+                key = (W.shape, W.tobytes())
+                w = windex.setdefault(key, len(wgroups))
                 same[ids] = w
                 if w == len(wgroups):
-                    wgroups.append((W, []))
+                    wgroups.append((W, [], key))
                     parts.append([])
             wgroups[w][1].append((g, A, j))
             parts[w].append(j)
@@ -1186,24 +1244,25 @@ class PallasBackend:
             # gang's requests fill the bm-row tile the padding would have
             # wasted.  Choose by padded-row cost; ~64 rows approximates
             # the fixed per-launch dispatch cost of an extra call.
-            subgroups: Dict[bytes, List[int]] = {}
-            for t, W in enumerate(Ws):
-                subgroups.setdefault(W.tobytes(), []).append(t)
+            keys = [plan.wgroups[wi][2] for plan in plans]
+            subgroups: Dict[tuple, List[int]] = {}
+            for t, key in enumerate(keys):
+                subgroups.setdefault(key, []).append(t)
             cost_vmap = T * Rp
             cost_concat = sum(-(-(len(g) * Rg) // bm) * bm
                               for g in subgroups.values()) \
                 + 64 * (len(subgroups) - 1)
+            wbytes = Kp * Cp       # one padded weight operand
             mats: List[Optional[np.ndarray]] = [None] * T
             if len(subgroups) < T and cost_concat < cost_vmap:
-                for g in subgroups.values():
+                for key, g in subgroups.items():
                     with clock.phase("stage"):
                         Rp2 = -(-(len(g) * Rg) // bm) * bm
                         Ap = np.zeros((Rp2, Kp), np.int8)
                         for j, t in enumerate(g):
                             Ap[j * Rg:(j + 1) * Rg, :K] = rows_of(t, wi)
-                        Wp = np.zeros((Kp, Cp), np.int8)
-                        Wp[:K, :Cg] = Ws[g[0]].T
-                        args = (jnp.asarray(Ap), jnp.asarray(Wp))
+                        Wd, hit = _wgt_operand(key, Ws[g[0]], Kp, Cp)
+                        args = (jnp.asarray(Ap), Wd)
                     with clock.phase("launch"):
                         out = gemm_call(*args)
                     with clock.phase("sync"):
@@ -1211,24 +1270,32 @@ class PallasBackend:
                     for s_ in {id(statss[t]): statss[t] for t in g}.values():
                         s_.tile_batches += 1
                         s_.lut_launches += int(use_lut)
+                        s_.upload_bytes += Ap.nbytes + wbytes * (not hit)
+                        s_.wgt_bytes += wbytes
+                        s_.wgt_hit_bytes += wbytes * hit
                     for j, t in enumerate(g):
                         mats[t] = out[j * Rg:(j + 1) * Rg,
                                       :Cg].astype(np.int32)
             else:
                 with clock.phase("stage"):
-                    Aps, Wps = [], []
+                    Ap = np.zeros((T, Rp, Kp), np.int8)
                     for t in range(T):
-                        Ap = np.zeros((Rp, Kp), np.int8)
-                        Ap[:Rg, :K] = rows_of(t, wi)
-                        Wp = np.zeros((Kp, Cp), np.int8)
-                        Wp[:K, :Cg] = Ws[t].T
-                        Aps.append(Ap)
-                        Wps.append(Wp)
+                        Ap[t, :Rg, :K] = rows_of(t, wi)
+                    wdev, hits = {}, 0
+                    for key, g in subgroups.items():
+                        wdev[key], hit = _wgt_operand(key, Ws[g[0]], Kp, Cp)
+                        hits += hit
                     if T == 1:
-                        args = (jnp.asarray(Aps[0]), jnp.asarray(Wps[0]))
+                        args = (jnp.asarray(Ap[0]), wdev[keys[0]])
+                    elif len(wdev) == 1:
+                        # every lane multiplies the same tile: one copy
+                        # on the device, broadcast there
+                        Wd = wdev[keys[0]]
+                        args = (jnp.asarray(Ap),
+                                jnp.broadcast_to(Wd, (T,) + Wd.shape))
                     else:
-                        args = (jnp.asarray(np.stack(Aps)),
-                                jnp.asarray(np.stack(Wps)))
+                        args = (jnp.asarray(Ap),
+                                jnp.stack([wdev[k] for k in keys]))
                 with clock.phase("launch"):
                     if T == 1:
                         outs = [gemm_call(*args)]
@@ -1241,6 +1308,10 @@ class PallasBackend:
                 for s_ in {id(s_): s_ for s_ in statss}.values():
                     s_.tile_batches += 1
                     s_.lut_launches += int(use_lut)
+                    s_.upload_bytes += Ap.nbytes \
+                        + wbytes * (len(wdev) - hits)
+                    s_.wgt_bytes += wbytes * len(wdev)
+                    s_.wgt_hit_bytes += wbytes * hits
                 with clock.phase("sync"):
                     outs = np.asarray(outs)
                 for t in range(T):
@@ -1261,19 +1332,19 @@ class PallasBackend:
                         else self._scatter(results, tile.grid, sh, spec))
         if shift is None and tiles[0].alu_chain:
             accs = self._alu_chain_batch(accs, [t.alu_chain for t in tiles],
-                                         clock)
+                                         clock, statss)
         return accs
 
     def _alu_chain_batch(self, accs: List[np.ndarray],
-                         chains: Sequence[Sequence[tuple]], clock: _Clock
-                         ) -> List[np.ndarray]:
+                         chains: Sequence[Sequence[tuple]], clock: _Clock,
+                         statss: Sequence[RunStats]) -> List[np.ndarray]:
         """Apply structurally-identical per-tile ALU chains to the whole
         tile batch in one pass: accumulators row-stack into a single
         matrix, tensor operands (bias rows) stack the same way, and each
         chain step becomes ONE tensor_alu launch for all tiles."""
         T = len(accs)
         if T == 1:
-            return [self._alu_chain(accs[0], chains[0], clock)]
+            return [self._alu_chain(accs[0], chains[0], clock, statss)]
         R = accs[0].shape[0]
         with clock.phase("stage"):
             x = np.concatenate(accs, axis=0)
@@ -1285,7 +1356,7 @@ class PallasBackend:
                     chain.append(("tensor", entry[1],
                                   np.concatenate([c[i][2] for c in chains],
                                                  axis=0)))
-        out = self._alu_chain(x, chain, clock)
+        out = self._alu_chain(x, chain, clock, statss)
         return [out[t * R:(t + 1) * R] for t in range(T)]
 
     def _scatter(self, results: Sequence[Tuple[int, np.ndarray]],
@@ -1304,16 +1375,19 @@ class PallasBackend:
         return self._to_matrix(
             acc.reshape(io, ii, spec.batch, spec.block_out), spec)
 
-    def _alu_chain(self, acc, chain: Sequence[tuple],
-                   clock: _Clock) -> "np.ndarray":
+    def _alu_chain(self, acc: np.ndarray, chain: Sequence[tuple],
+                   clock: _Clock, statss: Sequence[RunStats]
+                   ) -> np.ndarray:
         """Apply the recorded epilogue; consecutive immediate ops fuse into
-        one tensor_alu pass (the §2.5 resource-balance trade).  `acc` may
-        be a numpy or on-device array; returns the same shape."""
+        one tensor_alu pass (the §2.5 resource-balance trade).  Returns
+        the same shape as `acc`.  The bytes handed to the device count
+        once on each run in `statss`."""
         import jax.numpy as jnp
 
         from ..kernels.tensor_alu import tensor_alu
         with clock.phase("stage"):
             x = jnp.asarray(acc)
+        up = acc.nbytes
         i = 0
         while i < len(chain):
             if chain[i][0] == "imm":
@@ -1330,10 +1404,13 @@ class PallasBackend:
                 _, op, src = chain[i]
                 with clock.phase("stage"):
                     y = jnp.asarray(src)
+                up += src.nbytes
                 with clock.phase("launch"):
                     x = tensor_alu(x, y, chain=((op, None),),
                                    use_pallas=True, interpret=self.interpret)
                 i += 1
+        for s_ in {id(s_): s_ for s_ in statss}.values():
+            s_.upload_bytes += up
         with clock.phase("sync"):
             return np.asarray(x, dtype=np.int32)
 

@@ -157,6 +157,15 @@ class RunStats:
     # first run recorded (decoded uops, index structure, tile
     # bookkeeping), 0 when it analysed the stream or fell back part-way
     plan_hit: int = 0
+    # PallasBackend stage-phase bytes: what the engine handed to the
+    # device as kernel operands (GEMM activations and weights, ALU
+    # inputs and tensor operands), each launch's bytes added to every
+    # run that took part in it; and, of the padded GEMM weight operands
+    # each launch needed, all their bytes and the part the
+    # device-resident weight cache served (backend.wgt_cache_info)
+    upload_bytes: int = 0
+    wgt_bytes: int = 0
+    wgt_hit_bytes: int = 0
     # expert layers (Program.expert_matmul), on the segment of each
     # routed expert's stream: the call's live rows routed to that expert,
     # the rows launched for it (live rounded up to the template's batch),
@@ -198,7 +207,8 @@ class RunStats:
                       "eager_alu_insns", "n_join_barriers",
                       "n_buffer_fences", "staging_bytes_per_call",
                       "tiles_resolved", "tile_batches", "lut_launches",
-                      "decode_evictions", "plan_hit", "moe_rows",
+                      "decode_evictions", "plan_hit", "upload_bytes",
+                      "wgt_bytes", "wgt_hit_bytes", "moe_rows",
                       "moe_rows_padded", "moe_experts_hit",
                       "tune_cache_hits", "tune_cache_misses"):
                 setattr(out, f, getattr(out, f) + getattr(r, f))

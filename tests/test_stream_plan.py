@@ -26,9 +26,11 @@ from repro.core.serve import DevicePool
 
 _EP = Epilogue(shift=6, relu=True)
 _CONV = ConvShape(n=1, h=8, w=8, ic=32, oc=32, kh=3, kw=3, stride=1, pad=1)
-# RunStats fields a replay may read differently from a fresh analysis
+# RunStats fields a replay may read differently from a fresh analysis:
+# the clocks, the plan hit, and what the process-wide weight-operand
+# cache already held
 _TIMING = {"wall_time_s", "stage_s", "launch_s", "sync_s", "park_s",
-           "queue_s", "plan_hit"}
+           "queue_s", "plan_hit", "upload_bytes", "wgt_hit_bytes"}
 
 
 def _empty() -> None:
