@@ -130,8 +130,8 @@ def main() -> None:
     print("cross-backend check ok: "
           + ", ".join(f"{r.backend} {r.stats.wall_time_s * 1e3:.0f} ms"
                       for r in report.runs)
-          + "  (pallas time includes one-time jit compile; see "
-            "benchmarks/bench_kernels.py for warmed steady-state)")
+          + "  (pallas time includes one-time jit compile; the chip's "
+            "steady state is bench/run.py's to measure)")
 
     # --- 7. program-level JIT: a whole graph in ONE stream ---
     w2 = rng.normal(size=(128, 256)).astype(np.float32) / np.sqrt(256)

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed by the entry points.
 
-``chip_smoke.py``, ``benchmarks/run.py`` and the examples call
+``chip_smoke.py``, ``bench/run.py`` and the examples call
 :func:`enable` once before their first compile.  Importing ``repro``
 never touches the cache settings, so library users and the test suite
 keep JAX's defaults.

@@ -200,13 +200,12 @@ def _wgt_operand(key: tuple, W: np.ndarray, Kp: int, Cp: int
 
 @dataclass
 class _Decoded:
-    """One decoded stream as the cache holds it: its instructions and,
-    per setting of the switches that shape the engine's analysis, the
-    :class:`_StreamPlan` recorded from its first run.  ``plans`` is None
-    where the engine keeps no plan (``cache_decode=False`` or a cache
-    cap of 0); it is read and written under _DECODE_LOCK."""
+    """One decoded stream as the cache holds it: its instructions and the
+    :class:`_StreamPlan` recorded from its first run (None until then).
+    ``plan`` is published under _DECODE_LOCK; an entry that a cache cap
+    of 0 keeps out of the cache takes its plan with it."""
     insns: List[Insn]
-    plans: Optional[Dict[tuple, "_StreamPlan"]] = None
+    plan: Optional["_StreamPlan"] = None
 
 
 class _Step(NamedTuple):
@@ -354,40 +353,20 @@ class PallasBackend:
     arbitrary valid streams still execute correctly — just without the
     fast path.  ``RunStats.coalesced_*`` / ``eager_*`` count which route
     each compute instruction took (see :func:`assert_fast_path`).
-
-    ``coalesce_subgrids=False`` restricts coalescing to instructions whose
-    grid equals the tile's reset grid exactly (the pre-generalization
-    behavior, which sent direct-conv schedules to the eager loop) — kept
-    as an A/B switch for benchmarks and debugging.  ``batch_tiles=False``
-    likewise disables the batched tile dispatch (one kernel launch per
-    pending tile, the pre-serving-path behavior).
     """
 
     name = "pallas"
 
-    #: auto LUT selection: per-tile activation rows at or below this are
-    #: "decode-shaped" and route to the LUT-GEMM (bit-plane) kernel when
-    #: weights are sub-byte.  Fitted to the interpreter; on the MXU that
-    #: kernel costs one int8 pass per weight bit, so the value awaits a
-    #: chip measurement
+    #: LUT selection: a GEMM launch of sub-byte weights whose activation
+    #: rows are at or below this is "decode-shaped" and runs the LUT-GEMM
+    #: (bit-plane) kernel; every other launch runs the dense kernel.
+    #: Fitted to the interpreter; on the MXU that kernel costs one int8
+    #: pass per weight bit, so the value awaits a chip measurement
     LUT_MAX_ROWS = 16
 
-    def __init__(self, interpret: Optional[bool] = None,
-                 check_tokens: bool = True,
-                 coalesce_subgrids: bool = True,
-                 batch_tiles: bool = True,
-                 cache_decode: bool = True,
-                 use_lut: Optional[bool] = None):
+    def __init__(self, interpret: Optional[bool] = None):
         # interpret=None follows the platform (see resolved_interpret)
         self.interpret = interpret
-        self.check_tokens = check_tokens
-        self.coalesce_subgrids = coalesce_subgrids
-        self.batch_tiles = batch_tiles
-        self.cache_decode = cache_decode
-        # use_lut: None -> auto (sub-byte weights AND decode-shaped tiles);
-        # True forces the LUT kernel for every sub-byte GEMM; False pins
-        # the dense kernel (A/B baseline).  int8 specs never use it.
-        self.use_lut = use_lut
 
     @property
     def resolved_interpret(self) -> bool:
@@ -396,15 +375,6 @@ class PallasBackend:
         constructor's `interpret`, with None resolved from the platform."""
         from ..kernels._platform import resolve_interpret
         return resolve_interpret(self.interpret)
-
-    def _lut_select(self, spec: HardwareSpec, rows: int) -> bool:
-        """Per-shape kernel choice for one GEMM launch group: LUT-GEMM
-        (one MXU pass per weight bit plane) vs dense MXU GEMM.  Both are
-        bit-exact; this is purely a roofline call, so the fuzzer sweeps it
-        freely."""
-        if not spec.wgt_packed or self.use_lut is False:
-            return False
-        return bool(self.use_lut) or rows <= self.LUT_MAX_ROWS
 
     # ------------------------------------------------------------------
     def execute(self, spec: HardwareSpec, device: Device, stream: np.ndarray,
@@ -489,16 +459,13 @@ class PallasBackend:
         serving loop re-running one pre-staged stream pays the (pure
         python) decode exactly once.  Keyed on the bytes actually read
         from DRAM, so there is still no side channel.  The entry also
-        holds the stream's interpretation plans (:meth:`_run_gang`), so a
+        holds the stream's interpretation plan (:meth:`_run_gang`), so a
         plan is keyed by the same digest and evicted with its stream;
-        with ``cache_decode=False``, or a cap of 0, neither is kept.
-        Returns ``(decoded, evicted)`` where `evicted` counts LRU entries
-        this call pushed out of the bounded cache
-        (set_decode_cache_cap)."""
+        with a cap of 0 neither is kept.  Returns ``(decoded, evicted)``
+        where `evicted` counts LRU entries this call pushed out of the
+        bounded cache (set_decode_cache_cap)."""
         import hashlib
         global _DECODE_EVICTIONS
-        if not self.cache_decode:
-            return _Decoded(isa.decode_stream(raw)), 0
         key = (spec, hashlib.sha1(raw.tobytes()).hexdigest())
         with _DECODE_LOCK:
             hit = _DECODE_CACHE.pop(key, None)
@@ -513,7 +480,6 @@ class PallasBackend:
                 _DECODE_CACHE.pop(next(iter(_DECODE_CACHE)))
                 evicted += 1
             if _DECODE_CACHE_CAP > 0:
-                dec.plans = {}
                 _DECODE_CACHE[key] = dec
             _DECODE_EVICTIONS += evicted
         return dec, evicted
@@ -530,9 +496,8 @@ class PallasBackend:
         ``pending`` dicts stay key-synchronized throughout.
 
         Each instruction is analysed into a step (:meth:`_analyze`) that
-        the gang then applies.  The first run of a stream, per setting of
-        ``check_tokens``, ``coalesce_subgrids`` and ``batch_tiles``,
-        records its steps, the token check and the per-module counts as a
+        the gang then applies.  The first run of a stream records its
+        steps, the token check and the per-module counts as a
         :class:`_StreamPlan` in the stream's decode-cache entry
         (:meth:`_decode_cached`), built outside _DECODE_LOCK and
         published under it.  Later runs, at any gang width, replay the
@@ -550,15 +515,10 @@ class PallasBackend:
                                     for n in _MODULE_NAMES.values()})
                   for _ in devices]
         insns = dec.insns
-        switches = (self.check_tokens, self.coalesce_subgrids,
-                    self.batch_tiles)
-        plan = None
-        if dec.plans is not None:
-            with _DECODE_LOCK:
-                plan = dec.plans.get(switches)
+        plan = dec.plan
         if plan is None:
             counts, pushed = self._check_stream(insns)
-            done, steps = 0, [] if dec.plans is not None else None
+            done, steps = 0, []
         else:
             counts, pushed = plan.counts, plan.tokens_pushed
             done, steps = self._replay(plan.steps, insns, states, statss), None
@@ -583,7 +543,8 @@ class PallasBackend:
         if steps is not None:
             made = _StreamPlan(tuple(steps), counts, pushed)
             with _DECODE_LOCK:
-                dec.plans.setdefault(switches, made)
+                if dec.plan is None:
+                    dec.plan = made
         hit = int(plan is not None and done == len(insns))
         for stats in statss:
             for nm, n in counts:
@@ -594,19 +555,16 @@ class PallasBackend:
 
     def _check_stream(self, insns: Sequence[Insn]
                       ) -> Tuple[Tuple[Tuple[str, int], ...], int]:
-        """Per-module instruction counts and the dependence tokens pushed
-        (counted under ``check_tokens``, as the token protocol is checked).
-        The protocol is stream-determined, so it is checked once per
-        stream, before any instruction runs: a pop from an empty FIFO
-        raises DeadlockError."""
+        """Per-module instruction counts and the dependence tokens pushed,
+        counted as the token protocol is checked.  The protocol is
+        stream-determined, so it is checked once per stream, before any
+        instruction runs: a pop from an empty FIFO raises DeadlockError."""
         counts = {n: 0 for n in _MODULE_NAMES.values()}
         tokens = {"l2c": 0, "c2l": 0, "c2s": 0, "s2c": 0}
         pushed = 0
         for insn in insns:
             q = route_queue(insn)
             counts[_MODULE_NAMES[q]] += 1
-            if not self.check_tokens:
-                continue
             for fifo, flag in _IN_EDGES[q]:
                 if getattr(insn.dep, flag):
                     if tokens[fifo] == 0:
@@ -691,13 +649,11 @@ class PallasBackend:
         return need, self._peer_candidates(st0, need)
 
     def _peer_candidates(self, st0: _RunState, keys: Sequence[int]
-                         ) -> Optional[Tuple[int, ...]]:
+                         ) -> Tuple[int, ...]:
         """Pending tiles structurally like a forced one (``_pre_key``),
         which may resolve in the same launches; whether they do is the
         plan-key match, which reads weight bytes and is made in every
-        run (:meth:`_materialize_group`).  None: no peer sweep."""
-        if not self.batch_tiles:
-            return None
+        run (:meth:`_materialize_group`)."""
         pre = {self._pre_key(st0.pending[k]) for k in keys
                if st0.pending[k].chunks}
         if not pre:
@@ -737,17 +693,15 @@ class PallasBackend:
     def _find_containing(self, st: _RunState, grid: np.ndarray
                          ) -> Optional[Tuple[int, _PendingTile]]:
         """The pending tile this GEMM accumulates into: an exact grid
-        match (blocked matmul / im2col), or — with sub-grid coalescing —
-        any tile whose reset region contains every dst id (the direct-conv
-        per-output-row structure).  Returns (pending key, tile) so a gang
-        caller can fetch the same tile in every peer state."""
+        match (blocked matmul / im2col), or any tile whose reset region
+        contains every dst id (the direct-conv per-output-row structure).
+        Returns (pending key, tile) so a gang caller can fetch the same
+        tile in every peer state."""
         base = int(grid.min())
         tile = st.pending.get(base)
         if tile is not None and tile.grid.shape == grid.shape \
                 and (tile.grid == grid).all():
             return base, tile
-        if not self.coalesce_subgrids:
-            return None
         ids = grid.ravel()
         lo, hi = int(ids.min()), int(ids.max())
         for k, t in st.pending.items():
@@ -992,10 +946,6 @@ class PallasBackend:
         entries: List[Tuple[int, int, _PendingTile]] = \
             [(si, k, st.pending.pop(k))
              for si, st in enumerate(states) for k in keys]
-        if not self.batch_tiles:
-            for si, _, t in entries:
-                self._materialize(states[si], t, statss[si])
-            return
         groups: Dict[tuple, List[Tuple[int, _PendingTile, _TilePlan]]] = {}
         for si, k, t in entries:
             if t.chunks:
@@ -1004,7 +954,7 @@ class PallasBackend:
                 groups.setdefault(self._plan_key(plan), []).append(
                     (si, t, plan))
             else:
-                self._materialize(states[si], t, statss[si])  # reset/ALU-only
+                self._materialize(states[si], t, statss[si])
         for grp in groups.values():
             tiles_g = [t for _, t, _ in grp]
             plans_g = [p for _, _, p in grp]
@@ -1034,14 +984,12 @@ class PallasBackend:
 
     def _materialize(self, st: _RunState, tile: _PendingTile,
                      stats: RunStats) -> None:
+        """Resolve a tile with no GEMM chunks (a reset, and perhaps an
+        epilogue): zeros, through its ALU chain if it has one."""
         s = st.sim.spec
         io, ii = tile.grid.shape
         R, C = io * s.batch, ii * s.block_out
-        if tile.chunks:
-            plan = self._plan_tile(tile)
-            acc = self._resolve_tiles([tile], [plan], [stats], s,
-                                      st.clock)[0]
-        elif tile.alu_chain:
+        if tile.alu_chain:
             acc = self._alu_chain(np.zeros((R, C), np.int32), tile.alu_chain,
                                   st.clock, [stats])
         else:
@@ -1232,10 +1180,10 @@ class PallasBackend:
             # per-shape kernel choice: sub-byte weights on decode-shaped
             # tiles go through the LUT-GEMM kernel (same operands, same
             # epilogue contract, bit-identical output)
-            use_lut = self._lut_select(spec, Rg)
+            lut = spec.wgt_packed and Rg <= self.LUT_MAX_ROWS
 
             def gemm_call(Ap, Wp):
-                if use_lut:
+                if lut:
                     return lut_gemm_pallas(Ap, Wp, bits=spec.wgt_bits, **kw)
                 return vta_gemm_pallas(Ap, Wp, **kw)
             # tiles whose weight DATA is identical (gang members serving
@@ -1269,7 +1217,7 @@ class PallasBackend:
                         out = np.asarray(out)
                     for s_ in {id(statss[t]): statss[t] for t in g}.values():
                         s_.tile_batches += 1
-                        s_.lut_launches += int(use_lut)
+                        s_.lut_launches += int(lut)
                         s_.upload_bytes += Ap.nbytes + wbytes * (not hit)
                         s_.wgt_bytes += wbytes
                         s_.wgt_hit_bytes += wbytes * hit
@@ -1299,7 +1247,7 @@ class PallasBackend:
                 with clock.phase("launch"):
                     if T == 1:
                         outs = [gemm_call(*args)]
-                    elif use_lut:
+                    elif lut:
                         outs = jax.vmap(functools.partial(
                             lut_gemm_pallas, bits=spec.wgt_bits, **kw))(*args)
                     else:
@@ -1307,7 +1255,7 @@ class PallasBackend:
                                                           **kw))(*args)
                 for s_ in {id(s_): s_ for s_ in statss}.values():
                     s_.tile_batches += 1
-                    s_.lut_launches += int(use_lut)
+                    s_.lut_launches += int(lut)
                     s_.upload_bytes += Ap.nbytes \
                         + wbytes * (len(wdev) - hits)
                     s_.wgt_bytes += wbytes * len(wdev)

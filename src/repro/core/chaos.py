@@ -6,8 +6,8 @@ pool's accelerator-gang sequence number: *kill this slot at gang k*,
 :class:`serve.DevicePool` consumes the plan at the top of every gang
 execution, so a given (workload, seed) pair replays the exact same
 failure history run after run — the property the chaos fuzzer flavor
-and ``benchmarks/bench_chaos.py`` rely on to byte-diff every surviving
-request against a fault-free serial run.
+relies on to byte-diff every surviving request against a fault-free
+serial run.
 
 Faults model the three failure classes the recovery machinery handles:
 

@@ -187,15 +187,16 @@ def pynq_batch2() -> HardwareSpec:
     return HardwareSpec(batch=2, freq_mhz=200.0)
 
 
-# DMA/compute constants fitted against MEASURED Pallas kernel times by
-# ``benchmarks.bench_kernels.fit_timing_constants`` (dev container, jax
-# 0.4.37 CPU interpret mode, 2026-08): the pynq-template GEMM intrinsic
-# sustains ~2.8 GMAC/s through the interpreted kernel (-> ~11 MHz
-# effective at 256 MACs/cycle) and the simulated-DRAM memcpy path moves
-# ~7 GB/s (-> ~650 B/cycle, ~37 cycles fixed setup).  Re-run the fit on
-# new hardware (real TPU: orders of magnitude higher) and pass the result
-# to ``calibrated``; these recorded values make RunStats.total_cycles
-# predict interpret-mode wall-clock within a small factor on CI.
+# DMA/compute constants fitted against Pallas kernel times measured in
+# the interpreter (jax 0.4.37 on a CPU, 2026-08) by an interpret-mode
+# benchmark harness that has since been removed: the pynq-template GEMM
+# intrinsic sustained ~2.8 GMAC/s through the interpreted kernel
+# (-> ~11 MHz effective at 256 MACs/cycle) and the simulated-DRAM memcpy
+# path ~7 GB/s (-> ~650 B/cycle, ~37 cycles fixed setup).  They make
+# RunStats.total_cycles predict interpret-mode wall-clock within a small
+# factor on a CPU; no value here has been measured on the chip, where
+# the kernels run orders of magnitude faster, and the fit awaits that
+# measurement.
 HOST_FIT = dict(freq_mhz=11.0,
                 dram_rd_bytes_per_cycle=650.0,
                 dram_wr_bytes_per_cycle=650.0,
@@ -209,8 +210,8 @@ def calibrated(base: HardwareSpec | None = None,
     meaningful (predicts wall-clock) on BOTH engines — the simulator
     prices the stream with them directly, and ``PallasBackend`` replays
     the same TimingModel when given one.  Defaults to ``HOST_FIT`` (the
-    recorded dev-container fit); pass the output of
-    ``benchmarks.bench_kernels.fit_timing_constants()`` for this host."""
+    recorded interpret-mode fit); `fit` takes the same keys, measured on
+    the host the streams run on."""
     return (base or pynq()).replace(**(fit or HOST_FIT))
 
 

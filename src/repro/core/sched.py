@@ -72,9 +72,10 @@ from .simulator import TimingModel, replay_timing
 from .spans import span
 
 #: vmap interpret-mode cliff measured in PR 5: batching more than ~24
-#: tiles into one interpreted vmap launch stops amortizing dispatch
-#: overhead (BENCH_tiles.json, T=24).  The auto-tuner penalizes gang
-#: widths that push a segment's tiles-per-launch past this knee.
+#: tiles into one interpreted vmap launch stopped amortizing dispatch
+#: overhead on the host clock; not measured on the chip.
+#: The auto-tuner penalizes gang widths that push a segment's
+#: tiles-per-launch past this knee.
 VMAP_INTERPRET_CLIFF = 24
 
 SCHED_POLICIES = ("reject", "shed_oldest")

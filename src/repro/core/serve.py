@@ -92,7 +92,7 @@ Failure is also RECOVERABLE (the self-healing plane), opt-in per pool:
     instead of computing on corrupted bits.
   * **fault injection** (``fault_plan=chaos.FaultPlan(...)``) — a seeded
     script of kills / bit-flips / delays applied at gang boundaries, the
-    hook the chaos fuzzer flavor and ``benchmarks/bench_chaos.py`` drive.
+    hook the chaos fuzzer flavor drives.
 
 The simulator engine has no gang mode; a pool over ``backend=
 "simulator"`` runs its slots serially and acts as the concurrency

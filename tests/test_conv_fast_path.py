@@ -14,9 +14,10 @@ import numpy as np
 import pytest
 
 from repro.core import hwspec
-from repro.core.backend import PallasBackend, assert_fast_path
+from repro.core.backend import CrossBackendChecker, assert_fast_path
 from repro.core.conv import (ConvShape, conv2d_reference, read_conv_result,
                              schedule_conv2d)
+from repro.core.isa import COMPUTE_Q, LOAD_Q, STORE_Q, MemId
 from repro.core.program import Program
 from repro.core.runtime import Runtime
 from repro.core.scheduler import Epilogue
@@ -30,7 +31,7 @@ C4_LIKE = ConvShape(n=1, h=56, w=56, ic=32, oc=64, kh=3, kw=3,
                     stride=2, pad=1)
 
 
-def _run_pallas(shape, ep=None, lowering=None, spec=None, backend=None):
+def _run_pallas(shape, ep=None, lowering=None, spec=None):
     spec = spec or hwspec.pynq()
     rng = np.random.default_rng(shape.h * shape.ic + shape.oc)
     x = rng.integers(-64, 64, size=(shape.n, shape.ic, shape.h, shape.w),
@@ -39,7 +40,7 @@ def _run_pallas(shape, ep=None, lowering=None, spec=None, backend=None):
                      dtype=np.int8)
     rt = Runtime(spec)
     plan = schedule_conv2d(rt, x, w, shape, epilogue=ep, lowering=lowering)
-    stats = rt.synchronize(backend=backend or "pallas")
+    stats = rt.synchronize(backend="pallas")
     got = read_conv_result(rt, plan)
     want = conv2d_reference(x, w, shape, epilogue=ep)
     np.testing.assert_array_equal(got, want)
@@ -101,14 +102,50 @@ def test_batch_blocked_direct_conv_fast_path():
     assert_fast_path(stats)
 
 
-def test_subgrid_coalescing_switch_reverts_to_eager():
-    """coalesce_subgrids=False is the pre-generalization A/B baseline:
-    direct-conv GEMMs land in the eager loop again (and the result is
-    still bit-exact — the eager path is the correctness net)."""
-    shape = ConvShape(n=1, h=14, w=14, ic=32, oc=32, kh=3, kw=3,
-                      stride=1, pad=1)
-    stats = _run_pallas(shape, ep=Epilogue(shift=5),
-                        backend=PallasBackend(coalesce_subgrids=False))
+@pytest.mark.parametrize("spec", [hwspec.pynq(), hwspec.tpu_like()],
+                         ids=["pynq", "tpu_like"])
+def test_subgrid_coalescing_switch_reverts_to_eager(spec):
+    """A GEMM whose micro-op loop writes one accumulator twice has no
+    blocked-matmul structure, so the engine runs it with the simulator's
+    eager semantics: the correctness net, bit-exact against the
+    simulator, and flagged by ``assert_fast_path``."""
+    rng = np.random.default_rng(5)
+    a = rng.integers(-128, 128, size=(2, spec.batch, spec.block_in),
+                     dtype=np.int8)
+    w = rng.integers(-128, 128, size=(2, spec.block_out, spec.block_in),
+                     dtype=np.int8)
+    rt = Runtime(spec)
+    a_addr = rt.copy_to_device(a, align=spec.inp_elem_bytes)
+    w_addr = rt.copy_to_device(w, align=spec.wgt_elem_bytes)
+    c_addr = rt.buffer_alloc(spec.out_elem_bytes, align=spec.out_elem_bytes)
+    rt.load_buffer_2d(MemId.INP, 0, rt.to_elem_addr(a_addr, MemId.INP),
+                      1, 2, 2)
+    rt.load_buffer_2d(MemId.WGT, 0, rt.to_elem_addr(w_addr, MemId.WGT),
+                      1, 2, 2)
+    rt.dep_push(LOAD_Q, COMPUTE_Q)
+    rt.dep_pop(LOAD_Q, COMPUTE_Q)
+
+    def reset(b):
+        b.push(dst=0, src=0)
+
+    def twice(b):                       # acc[0] += a[i] @ w[i].T, i < 2
+        b.loop_begin(2, dst_factor=0, src_factor=1, wgt_factor=1)
+        b.push(dst=0, src=0, wgt=0)
+        b.loop_end()
+
+    rt.push_gemm(rt.uop_kernel(reset, key="t.rst"), reset=True)
+    rt.push_gemm(rt.uop_kernel(twice, key="t.twice"))
+    rt.dep_push(COMPUTE_Q, STORE_Q)
+    rt.dep_pop(COMPUTE_Q, STORE_Q)
+    rt.store_buffer_2d(0, rt.to_elem_addr(c_addr, MemId.OUT), 1, 1, 1)
+    report = CrossBackendChecker().check_runtime(rt)
+    assert report.matches, f"{report.mismatched_bytes} bytes differ"
+    want = sum(a[i].astype(np.int32) @ w[i].T.astype(np.int32)
+               for i in range(2)).astype(np.int8)
+    np.testing.assert_array_equal(
+        rt.copy_from_device(c_addr, spec.out_elem_bytes, np.int8,
+                            (spec.batch, spec.block_out)), want)
+    stats = report.stats_for("pallas")
     assert stats.eager_gemm_insns > 0
     assert stats.coalesced_gemm_insns == 0
     with pytest.raises(AssertionError, match="eager"):

@@ -12,6 +12,7 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import hwspec
+from repro.core.backend import resolve_backend
 from repro.core.isa import DepFlags, FinishInsn, Insn, Opcode, route_queue
 from repro.core.runtime import Runtime
 from repro.core.scheduler import matmul_reference, read_matmul_result, \
@@ -113,15 +114,17 @@ def test_validator_rejects_negative_balance():
         rt.validate_stream()
 
 
-def test_deadlock_detection():
-    """A pop with no pending producer must be detected, not hang."""
+@pytest.mark.parametrize("engine", ["simulator", "pallas"])
+def test_deadlock_detection(engine):
+    """A pop with no pending producer must be detected, not hang, on
+    either engine."""
     spec = hwspec.pynq()
     rt = Runtime(spec)
     rt.dep_pop(2, 3)
     rt.store_buffer_2d(0, 0, 1, 1, 1)
     stream = rt.isa.encode_stream(rt.stream + [FinishInsn(dep=DepFlags())])
     with pytest.raises(DeadlockError):
-        run_program(spec, rt.device, stream)
+        resolve_backend(engine).execute(spec, rt.device, stream)
 
 
 def test_tokens_actually_flow():

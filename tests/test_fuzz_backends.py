@@ -22,6 +22,7 @@ installed an additional property-based pass explores the same generator
 space.
 """
 import os
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -594,19 +595,21 @@ def _run_one_lowbit(seed: int) -> None:
             outs["buffer"][name], outs["barrier"][name],
             err_msg=f"seed={seed} node={name}: fenced stream diverged "
                     f"from the barrier baseline")
-    # kernel A/B on the Pallas engine: the LUT-GEMM path and the dense
-    # MXU path must both reproduce the numpy reference bit-exactly
+    # both GEMM kernels on the Pallas engine: with a row bound above
+    # every launch (all LUT-GEMM) and of 0 (all dense MXU) the result
+    # must reproduce the numpy reference bit-exactly
     compiled = p.compile(use_cache=False)
-    for use_lut in (True, False):
-        got = compiled(backend=PallasBackend(use_lut=use_lut), **feeds)
+    for lut_rows in (1 << 30, 0):
+        with mock.patch.object(PallasBackend, "LUT_MAX_ROWS", lut_rows):
+            got = compiled(backend=PallasBackend(), **feeds)
         if not isinstance(got, dict):
             got = {p.nodes[compiled.output_ids[0]].name: got}
         for i in compiled.output_ids:
             name = p.nodes[i].name
             np.testing.assert_array_equal(
                 got[name], refs[i],
-                err_msg=f"seed={seed} use_lut={use_lut} node={name}: "
-                        "kernel A/B diverged from the numpy reference")
+                err_msg=f"seed={seed} LUT_MAX_ROWS={lut_rows} node={name}: "
+                        "GEMM kernel diverged from the numpy reference")
 
 
 # ----------------------------------------------------------------------

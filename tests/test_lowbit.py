@@ -8,6 +8,8 @@ passing-after regressions for the three quantize.py bugs the path sits
 on top of (hard-coded int8 clip, overflow-before-clip, empty-input
 percentile crash).
 """
+from unittest import mock
+
 import numpy as np
 import pytest
 
@@ -249,8 +251,8 @@ def test_packed_constants_shrink_dram():
 
 def test_lut_selected_for_decode_shapes_only():
     """Per-shape kernel selection: decode-shaped (few-row) launches on a
-    sub-byte spec route through the LUT kernel; use_lut=False pins the
-    dense kernel; int8 specs never use it."""
+    sub-byte spec route through the LUT kernel; a row bound of 0 sends
+    them to the dense kernel; int8 specs never use it."""
     spec = hwspec.lowbit(4)
     w = RNG.integers(-8, 8, size=(128, 128)).astype(np.int8)
     x = RNG.integers(-128, 128, size=(2, 128)).astype(np.int8)
@@ -262,7 +264,8 @@ def test_lut_selected_for_decode_shapes_only():
     np.testing.assert_array_equal(got, want)
     assert sum(s.lut_launches for s in compiled.last_stats) >= 1
 
-    got = compiled(backend=PallasBackend(use_lut=False), x=x)
+    with mock.patch.object(PallasBackend, "LUT_MAX_ROWS", 0):
+        got = compiled(backend=PallasBackend(), x=x)
     np.testing.assert_array_equal(got, want)
     assert sum(s.lut_launches for s in compiled.last_stats) == 0
 

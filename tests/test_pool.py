@@ -19,7 +19,6 @@ import numpy as np
 import pytest
 
 from repro.core import hwspec
-from repro.core.backend import PallasBackend
 from repro.core.conv import ConvShape, conv2d_reference
 from repro.core.program import Program
 from repro.core.scheduler import Epilogue, matmul_reference
@@ -339,17 +338,3 @@ def test_bad_inputs_fail_fast_in_submit_and_bad_pool_args_raise():
             pool.submit(**ok).wait(timeout=120),
             c(backend="simulator", **ok))
 
-
-def test_gang_execute_respects_batch_tiles_ab_switch():
-    """The A/B switch still works through the pool: batch_tiles=False
-    resolves one launch per tile yet stays byte-exact."""
-    rng = np.random.default_rng(47)
-    p, make_request, reference = _mlp(rng)
-    c = p.compile(use_cache=False)
-    eng = PallasBackend(batch_tiles=False)
-    feeds = [make_request() for _ in range(4)]
-    with DevicePool(c, size=2, backend=eng) as pool:
-        futs = [pool.submit(**f) for f in feeds]
-        for f, feed in zip(futs, feeds):
-            np.testing.assert_array_equal(f.wait(timeout=240),
-                                          reference(feed))

@@ -149,13 +149,10 @@ def test_other_uop_bytes_fall_back_and_stay_exact(empty_cache):
     assert again.matches and again.stats_for("pallas").plan_hit == 1
 
 
-@pytest.mark.parametrize("way", ["cap-0", "cache_decode-off"])
-def test_no_plan_is_kept_without_the_decode_cache(empty_cache, way):
+def test_no_plan_is_kept_without_the_decode_cache(empty_cache):
     rt, stream, _ = _stream("direct")
-    eng = PallasBackend(cache_decode=way != "cache_decode-off")
-    checker = CrossBackendChecker(("simulator", eng))
-    if way == "cap-0":
-        set_decode_cache_cap(0)
+    checker = CrossBackendChecker(("simulator", PallasBackend()))
+    set_decode_cache_cap(0)
     for _ in range(3):
         rep = checker.run(rt.spec, rt.device, stream)
         assert rep.matches and rep.stats_for("pallas").plan_hit == 0

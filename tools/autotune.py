@@ -8,11 +8,16 @@ a stored baseline JSON (the old hillclimb-style report), and persists
 the winning decisions into a TuningCache file that ``Program.compile``
 auto-loads via ``REPRO_TUNE_CACHE``.
 
+Exits 1 unless, for every workload, the winner is validated, beats the
+base template on the oracle, is at least 1.1x faster measured, and a
+recompile of its program resolves every accelerator op from the tuning
+cache.
+
 Usage:
   PYTHONPATH=src python tools/autotune.py conv --seed 0 --candidates 24
   PYTHONPATH=src python tools/autotune.py matmul --m 128 --k 256 --n 256
   PYTHONPATH=src python tools/autotune.py both \\
-      --cache tuning_cache.json --baseline benchmarks/BENCH_autotune.json
+      --cache tuning_cache.json --out run.json --baseline base.json
 """
 import argparse
 import json
@@ -23,6 +28,34 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import autotune, hwspec                     # noqa: E402
 from repro.core.conv import ConvShape                       # noqa: E402
+
+#: the least measured speed-up over the base template that counts as a win
+MIN_MEASURED_SPEEDUP = 1.1
+
+
+def _winner_failures(wl, res) -> list:
+    """Why the search's winner on `wl` does not stand ([] where it does):
+    it must be validated, ahead of the base on the oracle and at least
+    MIN_MEASURED_SPEEDUP faster measured, and a recompile of its program
+    must resolve every accelerator op from the tuning cache."""
+    w = res.winner
+    if w is None or not w.validated:
+        return [f"{wl.name}: no validated winner"]
+    bad = []
+    if not w.predicted_cycles < res.baseline.predicted_cycles:
+        bad.append(f"{wl.name}: winner does not beat the base on the oracle")
+    if (res.speedup_measured or 0.0) < MIN_MEASURED_SPEEDUP:
+        bad.append(f"{wl.name}: measured speed-up "
+                   f"{res.speedup_measured or 0.0:.2f}x < "
+                   f"{MIN_MEASURED_SPEEDUP}x")
+    prog, _, _ = wl.build(w.candidate.spec, w.candidate.virtual_threads,
+                          w.candidate.lowering)
+    n_ops = sum(1 for n in prog.nodes if n.op in ("conv2d", "matmul"))
+    c = prog.compile(use_cache=False)
+    if (c.tune_hits, c.tune_misses) != (n_ops, 0):
+        bad.append(f"{wl.name}: recompiled winner resolved {c.tune_hits} "
+                   f"of {n_ops} op(s) from the tuning cache")
+    return bad
 
 
 def _diff_vs_baseline(result_json: dict, baseline_path: str) -> None:
@@ -75,8 +108,8 @@ def main(argv=None):
                     help="TuningCache JSON to merge winners into "
                          "(load+save; point REPRO_TUNE_CACHE here)")
     ap.add_argument("--baseline", default=None,
-                    help="stored trajectory JSON to diff the winner "
-                         "against (e.g. benchmarks/BENCH_autotune.json)")
+                    help="stored trajectory JSON (an earlier --out) to "
+                         "diff the winner against")
     ap.add_argument("--out", default=None,
                     help="write this run's trajectory JSON here")
     args = ap.parse_args(argv)
@@ -100,12 +133,14 @@ def main(argv=None):
 
     out = {"seed": args.seed, "base_spec": autotune.spec_key(base_spec),
            "workloads": []}
+    failures = []
     for wl in workloads:
         res = autotune.search(wl, base_spec=base_spec, seed=args.seed,
                               n_candidates=args.candidates,
                               top_n=args.top, repeats=args.repeats,
                               cache=cache, log=print)
         out["workloads"].append(res.to_json())
+        failures += _winner_failures(wl, res)
         if res.winner is not None:
             cfg = res.sched_config()
             print(f"  serving knobs: gang_width={cfg.gang_width} "
@@ -120,7 +155,9 @@ def main(argv=None):
         print(f"trajectory written to {args.out}")
     if args.baseline:
         _diff_vs_baseline(out, args.baseline)
-    return 0
+    for msg in failures:
+        print(f"FAIL {msg}", file=sys.stderr)
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
