@@ -34,6 +34,7 @@ importing :mod:`repro.core` stays numpy-only.
 from __future__ import annotations
 
 import contextlib
+import hashlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -42,6 +43,7 @@ from typing import Dict, List, NamedTuple, Optional, Protocol, Sequence, \
 
 import numpy as np
 
+from . import layout
 from .driver import Device
 from .hwspec import HardwareSpec
 from .isa import (AluInsn, AluOp, DEP_IN_EDGES, DEP_OUT_EDGES, FinishInsn,
@@ -146,14 +148,16 @@ def decode_cache_info() -> Dict[str, int]:
                 "evictions": _DECODE_EVICTIONS}
 
 
-# content-addressed, device-resident cache of padded GEMM weight
-# operands (see _wgt_operand): a constant weight tile is padded,
-# transposed and uploaded once, and every later launch that needs the
-# same bytes takes the device array.  Keyed by the tile's exact content,
-# so weights that differ never share an entry.  Shared across backend
-# instances and serving threads under _WGT_LOCK; LRU order, bounded by
-# the padded device bytes it holds (the host keys are no larger), with
-# evictions counted.
+# device-resident cache of padded GEMM weight operands (see
+# _wgt_operand): a constant weight tile is padded, transposed and
+# uploaded once, and every later launch that needs the same bytes takes
+# the device array.  Keyed by where the operand's bytes come from (the
+# digests of its WGT LOADs' DRAM ranges, see _WgtSource) or, where that
+# cannot be named, by the bytes themselves; either way weights that
+# differ never share an entry.  Shared across backend instances and
+# serving threads under _WGT_LOCK; LRU order, bounded by the padded
+# device bytes it holds (the host keys are smaller), with evictions
+# counted.
 _WGT_CACHE: Dict[tuple, object] = {}
 _WGT_LOCK = threading.Lock()
 _WGT_CACHE_CAP_BYTES = 512 << 20
@@ -170,13 +174,15 @@ def wgt_cache_info() -> Dict[str, int]:
                 "evictions": _WGT_EVICTIONS}
 
 
-def _wgt_operand(key: tuple, W: np.ndarray, Kp: int, Cp: int
+def _wgt_operand(key: tuple, ws: Sequence["_WgtOperand"], Kp: int, Cp: int
                  ) -> Tuple[object, bool]:
     """The (Kp, Cp) int8 device operand of the weight tile `W` ((C, K),
-    transposed and zero-padded; `Kp`, `Cp` follow from its shape), and
-    whether the cache held it.  `key` is ``(W.shape, W.tobytes())``.
-    A miss builds and uploads the operand outside the lock and keeps
-    it, evicting the least-recently-used entries to stay in the bound."""
+    the K-concatenation of the chunk operands `ws`, transposed and
+    zero-padded; `Kp`, `Cp` follow from its shape), and whether the
+    cache held it.  `key` is the plan's key of `ws` (``_plan_tile``).
+    Only a miss builds `W`'s bytes; it uploads the operand outside the
+    lock and keeps it, evicting the least-recently-used entries to stay
+    in the bound."""
     import jax.numpy as jnp
     global _WGT_CACHE_BYTES, _WGT_EVICTIONS
     with _WGT_LOCK:
@@ -184,6 +190,8 @@ def _wgt_operand(key: tuple, W: np.ndarray, Kp: int, Cp: int
         if hit is not None:
             _WGT_CACHE[key] = hit      # re-insert: LRU order by last hit
             return hit, True
+    W = ws[0].array() if len(ws) == 1 else \
+        np.concatenate([w.array() for w in ws], axis=1)
     Wp = np.zeros((Kp, Cp), np.int8)
     Wp[:W.shape[1], :W.shape[0]] = W.T
     dev = jnp.asarray(Wp)
@@ -261,13 +269,78 @@ class _TileLayout:
 @dataclass
 class _TilePlan:
     """One tile's resolution plan for one run (``_plan_tile``): GEMM
-    stages ``wgroups = [(W, [(grid, A, group), ...], key), ...]``, one
-    per distinct weight tile (`key` is ``(W.shape, W.tobytes())``, the
-    tile's content, which also keys the weight-operand cache), and which
-    grid groups each stage holds."""
+    stages ``wgroups = [(ws, [(grid, A, group), ...], key), ...]``, one
+    per distinct weight tile (`ws` the chunk operands whose
+    K-concatenation it is, `key` the tuple of their keys, which also
+    keys the weight-operand cache), and which grid groups each stage
+    holds."""
     wgroups: list
     shape: _TileShape
     partition: Tuple[Tuple[int, ...], ...]
+
+
+class _WgtLoad:
+    """One deferred WGT LOAD of a run: its instruction and stream
+    position, the DRAM range it reads (``[lo, lo + span)``) and that
+    range's digest, the bytes it names, and whether the host has copied
+    any of them out of DRAM (into the WGT SRAM or an operand)."""
+    __slots__ = ("insn", "pos", "lo", "span", "nbytes", "digest", "copied")
+
+    def __init__(self, insn: LoadStoreInsn, pos: int, lo: int, span: int,
+                 nbytes: int, digest: bytes):
+        self.insn, self.pos, self.lo, self.span = insn, pos, lo, span
+        self.nbytes, self.digest, self.copied = nbytes, digest, False
+
+
+@dataclass(frozen=True, eq=False)
+class _WgtSource:
+    """Where a weight slot's operand comes from, as the analysis finds
+    it: the stream positions of the WGT LOADs whose DRAM data its rows
+    hold, each distinct SRAM row's byte address (-1: a zero row), the
+    slot's rows as indices into those, and the operand's shape.
+    ``static`` digests everything but the loads' DRAM bytes, so the
+    key ``(static, digest of each load's range...)`` is equal only for
+    operands of equal bytes."""
+    static: bytes
+    positions: Tuple[int, ...]
+    addrs: np.ndarray
+    inv: np.ndarray
+    shape: Tuple[int, int]
+
+
+class _WgtOperand:
+    """A GEMM chunk's weight operand ((ii*block_out, U*block_in) int8):
+    its cache key and shape, and its bytes once built.  Built from its
+    WGT LOADs' DRAM ranges (``src``: the Dram, the :class:`_WgtSource`,
+    the loads) only when something needs them: a weight-cache miss, or
+    a STORE about to overwrite one of those ranges."""
+    __slots__ = ("key", "shape", "arr", "src")
+
+    def __init__(self, key: tuple, shape: Tuple[int, int],
+                 arr: Optional[np.ndarray] = None, src: Optional[tuple] = None):
+        self.key, self.shape, self.arr, self.src = key, shape, arr, src
+
+    def array(self) -> np.ndarray:
+        if self.arr is None:
+            dram, ws, loads, spec = self.src
+            eb = spec.wgt_elem_bytes
+            raw = np.zeros((ws.addrs.size, eb), np.uint8)
+            for j, a in enumerate(ws.addrs.tolist()):
+                if a >= 0:
+                    raw[j] = dram.read(a, eb)
+            if spec.wgt_packed:
+                # the same unpack as the simulator's WGT DMA
+                rows = layout.unpack_wgt_elems(raw, spec.wgt_bits,
+                                               spec.block_out, spec.block_in)
+            else:
+                rows = raw.view(np.int8).reshape(-1, spec.block_out,
+                                                 spec.block_in)
+            self.arr = np.ascontiguousarray(
+                rows[ws.inv].transpose(0, 2, 1, 3).reshape(self.shape))
+            for ld in loads:
+                ld.copied = True
+            self.src = None
+        return self.arr
 
 
 def _frozen(a: np.ndarray) -> np.ndarray:
@@ -287,7 +360,7 @@ class _GemmChunk:
     ``oh``, each accumulating kh*kw*cbt uops into its row of the tile."""
     grid: np.ndarray                    # (iter_out, iter_in) acc element ids
     a: np.ndarray                       # (io*batch, U*block_in) int8
-    w: np.ndarray                       # (ii*block_out, U*block_in) int8
+    w: "_WgtOperand"                    # (ii*block_out, U*block_in) int8
 
 
 @dataclass
@@ -332,13 +405,25 @@ class _RunState:
     sim: Simulator                          # SRAM state + eager semantics
     clock: _Clock                           # shared by the whole gang
     pending: Dict[int, _PendingTile] = field(default_factory=dict)
-    # GEMM weight operands gathered in this run, by slot: chunks that
-    # gather the same weight rows with no WGT load between them share one
-    # gather.  The analysis (state 0) assigns the slots in `wslots`, by
-    # the rows' ids since the last WGT load; a slot is the position of
-    # the first instruction that gathered them
-    wsnap: Dict[int, np.ndarray] = field(default_factory=dict)
-    wslots: Dict[tuple, int] = field(default_factory=dict)
+    # GEMM weight operands of this run, by slot: chunks that read the
+    # same weight rows with no WGT load between them share one operand.
+    # The analysis (state 0) assigns the slots in `wslots`, by the rows'
+    # ids since the last WGT load, with their source; a slot is the
+    # position of the first instruction that read them
+    wops: Dict[int, _WgtOperand] = field(default_factory=dict)
+    wslots: Dict[tuple, Tuple[int, Optional[_WgtSource]]] = \
+        field(default_factory=dict)
+    # this run's deferred WGT LOADs by stream position, and those whose
+    # rows are not in the WGT SRAM yet, in stream order
+    wloads: Dict[int, _WgtLoad] = field(default_factory=dict)
+    wdefer: List[_WgtLoad] = field(default_factory=list)
+    # state 0 only: per WGT SRAM row, the position of the LOAD whose DRAM
+    # data it holds (-1: zeros; -2: bytes whose source is not named) and
+    # that data's byte address; and the DRAM ranges ``(lo, hi)`` of the
+    # deferred loads, by position, that a STORE must not overwrite unseen
+    wpos: Optional[np.ndarray] = None
+    waddr: Optional[np.ndarray] = None
+    wlive: Dict[int, Tuple[int, int]] = field(default_factory=dict)
 
 
 class PallasBackend:
@@ -501,9 +586,13 @@ class PallasBackend:
         :class:`_StreamPlan` in the stream's decode-cache entry
         (:meth:`_decode_cached`), built outside _DECODE_LOCK and
         published under it.  Later runs, at any gang width, replay the
-        steps and do only the data work: DMA, operand gathers (one per
-        weight slot, see ``_RunState.wsnap``), weight-byte grouping,
-        kernel launches and write-back.  Before a
+        steps and do only the data work: DMA, activation gathers, the
+        weight operands' keys (one per weight slot, see
+        ``_RunState.wops``), kernel launches and write-back.  A WGT LOAD
+        only records its DRAM range and that range's digest
+        (:meth:`_ap_wgt_load`): weight bytes are copied out of DRAM only
+        where an operand is not on the device, or where something reads
+        the WGT SRAM.  Before a
         replayed step is applied, the uop SRAM bytes and the pending-tile
         keys it was derived from are checked against state 0; on a
         mismatch that instruction and the rest of the stream are analysed
@@ -511,6 +600,8 @@ class PallasBackend:
         ``RunStats.plan_hit`` is 1 when the whole stream replayed."""
         states = [_RunState(sim=Simulator(spec, d), clock=clock)
                   for d in devices]
+        states[0].wpos = np.full(spec.wgt_depth, -1, np.int64)
+        states[0].waddr = np.full(spec.wgt_depth, -1, np.int64)
         statss = [RunStats(modules={n: ModuleStats()
                                     for n in _MODULE_NAMES.values()})
                   for _ in devices]
@@ -524,9 +615,7 @@ class PallasBackend:
             done, steps = self._replay(plan.steps, insns, states, statss), None
             if done < len(insns):
                 # the analysis takes over: plan what is pending afresh
-                # and gather weights anew
                 for st in states:
-                    st.wsnap.clear()
                     for t in st.pending.values():
                         t.layout = None
         for i in range(done, len(insns)):
@@ -546,11 +635,13 @@ class PallasBackend:
                 if dec.plan is None:
                     dec.plan = made
         hit = int(plan is not None and done == len(insns))
-        for stats in statss:
+        for st, stats in zip(states, statss):
             for nm, n in counts:
                 stats.modules[nm].insn_count = n
             stats.tokens_pushed = pushed
             stats.plan_hit = hit
+            stats.wgt_skip_bytes = sum(ld.nbytes for ld in st.wloads.values()
+                                       if not ld.copied)
         return statss
 
     def _check_stream(self, insns: Sequence[Insn]
@@ -608,16 +699,21 @@ class PallasBackend:
             if insn.opcode == Opcode.STORE:
                 lo = insn.sram_base
                 hi = insn.sram_base + insn.y_size * insn.x_size
-            elif insn.memory_type in (MemId.ACC, MemId.OUT):
+                return _Step("_ap_store", None, tuple(st0.pending),
+                             (self._store_pins(st0, insn),)
+                             + self._range_need(st0, lo, hi))
+            if insn.memory_type in (MemId.ACC, MemId.OUT):
                 # both land in tile-owned state: ACC loads overwrite
                 # accumulators, OUT loads overwrite the write-through
                 # mirror a later STORE reads
                 width = insn.x_pad_0 + insn.x_size + insn.x_pad_1
                 rows = insn.y_pad_0 + insn.y_size + insn.y_pad_1
                 lo, hi = insn.sram_base, insn.sram_base + rows * width
+            elif insn.memory_type == MemId.WGT:
+                st0.wslots.clear()          # weight rows change: new slots
+                return _Step("_ap_wgt_load", None, None,
+                             self._wgt_load_args(i, insn, st0.sim.spec))
             else:
-                if insn.memory_type == MemId.WGT:
-                    st0.wslots.clear()      # weight rows change: new slots
                 return _Step("_ap_commit")
             return _Step("_ap_commit", None, tuple(st0.pending),
                          self._range_need(st0, lo, hi))
@@ -632,6 +728,45 @@ class PallasBackend:
             else self._analyze_alu
         apply, args = analyze(i, st0, insn, uops)
         return _Step(apply, words.tobytes(), tuple(st0.pending), args)
+
+    @staticmethod
+    def _wgt_load_args(i: int, insn: LoadStoreInsn, spec: HardwareSpec
+                       ) -> tuple:
+        """The arguments of WGT LOAD `i`'s step: its first SRAM row, per
+        row it writes the LOAD's position (-1 for a padding row) and the
+        byte address of the row's DRAM data (-1), its DRAM range
+        ``(lo, span)`` and the bytes it names.  Rows past the WGT SRAM
+        leave the load to the simulator's DMA (positions None)."""
+        eb = spec.wgt_elem_bytes
+        width = insn.x_pad_0 + insn.x_size + insn.x_pad_1
+        n_rows = (insn.y_pad_0 + insn.y_size + insn.y_pad_1) * width
+        nbytes = insn.y_size * insn.x_size * eb
+        if insn.sram_base + n_rows > spec.wgt_depth:
+            return i, insn.sram_base, None, None, (0, 0), nbytes
+        addrs = np.full(n_rows, -1, np.int64)
+        data = addrs[insn.y_pad_0 * width:
+                     (insn.y_pad_0 + insn.y_size) * width]
+        data.reshape(insn.y_size, width)[
+            :, insn.x_pad_0:insn.x_pad_0 + insn.x_size] = (
+                insn.dram_base
+                + np.arange(insn.y_size)[:, None] * insn.x_stride
+                + np.arange(insn.x_size)) * eb
+        span = ((insn.y_size - 1) * insn.x_stride + insn.x_size) * eb \
+            if nbytes else 0
+        return (i, insn.sram_base, _frozen(np.where(addrs >= 0, i, -1)),
+                _frozen(addrs), (insn.dram_base * eb, span), nbytes)
+
+    @staticmethod
+    def _store_pins(st0: _RunState, insn: LoadStoreInsn) -> Tuple[int, ...]:
+        """The positions of the deferred WGT LOADs whose DRAM ranges the
+        STORE `insn` overwrites (in the order they were recorded)."""
+        if not st0.wlive or insn.y_size == 0 or insn.x_size == 0:
+            return ()
+        eb = st0.sim.spec.out_elem_bytes
+        lo = insn.dram_base * eb
+        hi = lo + ((insn.y_size - 1) * insn.x_stride + insn.x_size) * eb
+        return tuple(p for p, (a, b) in st0.wlive.items()
+                     if a < hi and lo < b)
 
     def _range_need(self, st0: _RunState, lo: int, hi: int) -> tuple:
         """``(keys, peers)`` of a store or ACC/OUT load over SRAM
@@ -738,10 +873,42 @@ class PallasBackend:
         s = st0.sim.spec
         macs = grid.size * src_idx.shape[1] * s.batch * s.block_in \
             * s.block_out
-        wslot = st0.wslots.setdefault((wgt_idx.shape, wgt_idx.tobytes()), i)
+        wkey = (wgt_idx.shape, wgt_idx.tobytes())
+        if wkey not in st0.wslots:
+            st0.wslots[wkey] = (i, self._wgt_source(st0, wgt_idx))
+        wslot, wsrc = st0.wslots[wkey]
         return "_ap_chunk", (
             found[0], _frozen(grid), _frozen(src_idx), _frozen(wgt_idx),
-            wslot, macs)
+            wslot, wsrc, macs)
+
+    @staticmethod
+    def _wgt_source(st0: _RunState, wgt_idx: np.ndarray
+                    ) -> Optional[_WgtSource]:
+        """The source of the weight operand that reads WGT SRAM rows
+        `wgt_idx` now, or None where a row holds bytes whose source is
+        not named (the operand is then gathered from the SRAM)."""
+        s = st0.sim.spec
+        rows, inv = np.unique(wgt_idx, return_inverse=True)
+        pos = st0.wpos[rows]
+        if (pos == -2).any():
+            return None
+        live = pos >= 0
+        positions = tuple(np.unique(pos[live]).tolist())
+        loads = [st0.wloads[p] for p in positions]
+        # per row: which of those loads, and the offset in its range
+        which = np.where(live, np.searchsorted(positions, pos), -1)
+        addrs = st0.waddr[rows]             # -1 on the zero rows
+        base = np.array([ld.lo for ld in loads] + [0], np.int64)[which]
+        rel = np.where(live, addrs - base, -1)
+        inv = inv.reshape(wgt_idx.shape)
+        shape = (wgt_idx.shape[0] * s.block_out,
+                 wgt_idx.shape[1] * s.block_in)
+        head = repr((s.wgt_bits, s.block_out, s.block_in, s.wgt_elem_bytes,
+                     shape, inv.shape, [ld.span for ld in loads]))
+        static = hashlib.sha1(head.encode() + which.tobytes() + rel.tobytes()
+                              + inv.astype(np.int64).tobytes()).digest()
+        return _WgtSource(static, positions, _frozen(addrs), _frozen(inv),
+                          shape)
 
     def _analyze_alu(self, i: int, st0: _RunState, insn: AluInsn, uops
                      ) -> Tuple[str, tuple]:
@@ -794,15 +961,89 @@ class PallasBackend:
                    peers: Optional[Sequence[int]] = None) -> None:
         """Resolve the pending tiles at `keys` (and matching `peers`),
         then run the instruction with the simulator's semantics: a DMA,
-        or a compute instruction the fast path does not take (eager)."""
+        or a compute instruction the fast path does not take (eager;
+        a GEMM first writes the deferred WGT LOADs into the SRAM)."""
         if keys:
             self._materialize_group(states, keys, statss, peers)
         gemm = int(isinstance(insn, GemmInsn))
         alu = int(isinstance(insn, AluInsn))
         for st, stats in zip(states, statss):
+            if gemm:
+                self._wgt_flush(st)
             st.sim._commit(insn, stats)
             stats.eager_gemm_insns += gemm
             stats.eager_alu_insns += alu
+
+    def _ap_wgt_load(self, states: Sequence[_RunState],
+                     statss: Sequence[RunStats], insn: LoadStoreInsn,
+                     i: int, row0: int, pos: Optional[np.ndarray],
+                     addrs: Optional[np.ndarray], rng: Tuple[int, int],
+                     nbytes: int) -> None:
+        """WGT LOAD `i`: record, in every state, the DRAM range its rows
+        come from and the range's digest, and copy nothing; the rows
+        reach the WGT SRAM only if something reads it
+        (:meth:`_wgt_flush`).  State 0's row map takes the rows'
+        sources.  A load past the SRAM (`pos` None) runs the simulator's
+        DMA after the deferred loads before it."""
+        st0 = states[0]
+        if pos is None:
+            for st, stats in zip(states, statss):
+                self._wgt_flush(st)
+                stats.wgt_load_bytes += nbytes
+                st.sim._commit(insn, stats)
+            st0.wpos[row0:] = -2
+            return
+        lo, span = rng
+        for st, stats in zip(states, statss):
+            stats.dram_rd_bytes += nbytes
+            stats.wgt_load_bytes += nbytes
+            if span:
+                ld = _WgtLoad(insn, i, lo, span, nbytes,
+                              st.sim.device.dram.region_digest(lo, span))
+                st.wloads[i] = ld
+                st.wdefer.append(ld)
+            else:
+                # only padding rows: zeros, with no source to wait for
+                st.wdefer.append(_WgtLoad(insn, i, lo, 0, 0, b""))
+        st0.wpos[row0:row0 + pos.size] = pos
+        st0.waddr[row0:row0 + pos.size] = addrs
+        if span:
+            st0.wlive[i] = (lo, lo + span)
+
+    def _wgt_flush(self, st: _RunState) -> None:
+        """Write `st`'s deferred WGT LOADs into its WGT SRAM, in stream
+        order, with the simulator's DMA (their DRAM ranges are unchanged
+        since they were recorded: a STORE that overwrites one flushes
+        first, see :meth:`_ap_store`)."""
+        if st.wdefer:
+            counted = RunStats()        # the loads' reads counted already
+            for ld in st.wdefer:
+                st.sim._commit(ld.insn, counted)
+                ld.copied = True
+            st.wdefer.clear()
+
+    def _ap_store(self, states: Sequence[_RunState],
+                  statss: Sequence[RunStats], insn: LoadStoreInsn,
+                  pins: Tuple[int, ...], keys: Sequence[int] = (),
+                  peers: Optional[Sequence[int]] = None) -> None:
+        """A STORE, after its forced tiles resolve (:meth:`_ap_commit`).
+        Where it overwrites the DRAM range of deferred WGT LOADs
+        (`pins`), every state first copies those bytes out while they
+        are still the ones loaded: the deferred loads go into the SRAM,
+        and the operands built from them are built now; state 0's rows
+        from them lose their named source."""
+        if pins:
+            st0 = states[0]
+            for st in states:
+                self._wgt_flush(st)
+                for op in st.wops.values():
+                    if op.src is not None and any(
+                            ld.pos in pins for ld in op.src[2]):
+                        op.array()
+            for p in pins:
+                del st0.wlive[p]
+            st0.wpos[np.isin(st0.wpos, pins)] = -2
+        self._ap_commit(states, statss, insn, keys, peers)
 
     def _ap_reset(self, states: Sequence[_RunState],
                   statss: Sequence[RunStats], insn: GemmInsn, base: int,
@@ -822,9 +1063,12 @@ class PallasBackend:
     def _ap_chunk(self, states: Sequence[_RunState],
                   statss: Sequence[RunStats], insn: GemmInsn, key: int,
                   grid: np.ndarray, src_idx: np.ndarray,
-                  wgt_idx: np.ndarray, wslot: int, macs: int) -> None:
+                  wgt_idx: np.ndarray, wslot: int,
+                  wsrc: Optional[_WgtSource], macs: int) -> None:
         """Add one coalesced GEMM chunk to the tile at `key` in every
-        state; the weight operand is gathered once per slot `wslot`."""
+        state; the weight operand is made once per slot `wslot`: keyed
+        by its source `wsrc`, its bytes left in DRAM, or, with no source
+        named, gathered from the WGT SRAM and keyed by its bytes."""
         s = states[0].sim.spec
         U = src_idx.shape[1]
         for st, stats in zip(states, statss):
@@ -835,13 +1079,22 @@ class PallasBackend:
             A2 = np.ascontiguousarray(
                 A.transpose(0, 2, 1, 3).reshape(grid.shape[0] * s.batch,
                                                 U * s.block_in))
-            W2 = st.wsnap.get(wslot)
-            if W2 is None:
-                Wm = sim.wgt_sram[wgt_idx]   # (ii, U, block_out, block_in)
-                W2 = st.wsnap[wslot] = np.ascontiguousarray(
-                    Wm.transpose(0, 2, 1, 3).reshape(
-                        grid.shape[1] * s.block_out, U * s.block_in))
-            st.pending[key].chunks.append(_GemmChunk(grid=grid, a=A2, w=W2))
+            op = st.wops.get(wslot)
+            if op is None:
+                if wsrc is not None:
+                    loads = [st.wloads[p] for p in wsrc.positions]
+                    op = _WgtOperand(
+                        (wsrc.static,) + tuple(ld.digest for ld in loads),
+                        wsrc.shape, src=(sim.device.dram, wsrc, loads, s))
+                else:
+                    self._wgt_flush(st)
+                    Wm = sim.wgt_sram[wgt_idx]   # (ii, U, bo, bi)
+                    W2 = np.ascontiguousarray(
+                        Wm.transpose(0, 2, 1, 3).reshape(
+                            grid.shape[1] * s.block_out, U * s.block_in))
+                    op = _WgtOperand((W2.shape, W2.tobytes()), W2.shape, W2)
+                st.wops[wslot] = op
+            st.pending[key].chunks.append(_GemmChunk(grid=grid, a=A2, w=op))
             stats.coalesced_gemm_insns += 1
             stats.gemm_macs += macs
 
@@ -1075,32 +1328,26 @@ class PallasBackend:
         — the direct-conv structure, one instruction per output row —
         row-stack into one GEMM per distinct weight tile.  The grouping
         by grid is the tile's :class:`_TileShape`; the grouping by weight
-        bytes is made in every run.  The shape's ``shift`` is the requant
-        shift when the ALU chain fuses into the kernel epilogue (chunk
-        grids pairwise disjoint + canonical shr/clip chain), else None."""
+        operand keys is made in every run.  The shape's ``shift`` is the
+        requant shift when the ALU chain fuses into the kernel epilogue
+        (chunk grids pairwise disjoint + canonical shr/clip chain), else
+        None."""
         sh = self._tile_shape(tile)
         ch = tile.chunks
-        wgroups: List[Tuple[np.ndarray,
+        wgroups: List[Tuple[Tuple[_WgtOperand, ...],
                             List[Tuple[np.ndarray, np.ndarray, int]]]] = []
         parts: List[List[int]] = []
-        # a stage is found by weight bytes; groups built from the very
-        # same weight arrays (a shared gather) are found without them
+        # a stage is found by its weight operands' keys
         windex: Dict[tuple, int] = {}
-        same: Dict[tuple, int] = {}
         for j, (g, idx) in enumerate(sh.groups):
             A = ch[idx[0]].a if len(idx) == 1 else \
                 np.concatenate([ch[i].a for i in idx], axis=1)
-            ids = tuple(id(ch[i].w) for i in idx)
-            w = same.get(ids)
-            if w is None:
-                W = ch[idx[0]].w if len(idx) == 1 else \
-                    np.concatenate([ch[i].w for i in idx], axis=1)
-                key = (W.shape, W.tobytes())
-                w = windex.setdefault(key, len(wgroups))
-                same[ids] = w
-                if w == len(wgroups):
-                    wgroups.append((W, [], key))
-                    parts.append([])
+            ws = tuple(ch[i].w for i in idx)
+            key = tuple(w.key for w in ws)
+            w = windex.setdefault(key, len(wgroups))
+            if w == len(wgroups):
+                wgroups.append((ws, [], key))
+                parts.append([])
             wgroups[w][1].append((g, A, j))
             parts[w].append(j)
         return _TilePlan(wgroups, sh, tuple(tuple(p) for p in parts))
@@ -1170,7 +1417,7 @@ class PallasBackend:
             Ws = [plan.wgroups[wi][0] for plan in plans]
             Rg = sum(A.shape[0] for _, A, _ in wgroups0[wi][1])
             K = wgroups0[wi][1][0][1].shape[1]
-            Cg = Ws[0].shape[0]
+            Cg = Ws[0][0].shape[0]
             Rp = -(-Rg // bm) * bm
             Cp = -(-Cg // bn) * bn
             Kp = -(-K // bk) * bk
@@ -1186,7 +1433,7 @@ class PallasBackend:
                 if lut:
                     return lut_gemm_pallas(Ap, Wp, bits=spec.wgt_bits, **kw)
                 return vta_gemm_pallas(Ap, Wp, **kw)
-            # tiles whose weight DATA is identical (gang members serving
+            # tiles whose weight keys are equal (gang members serving
             # the same constant weights) can row-concat into one taller
             # GEMM instead of spending a padded vmap lane each — the
             # gang's requests fill the bm-row tile the padding would have

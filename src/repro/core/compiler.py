@@ -117,19 +117,8 @@ class _WindowDram(Dram):
     variant into a :class:`JitWindow`."""
 
     def __init__(self, lo: int, hi: int, align: int):
-        self.lo, self.size, self.align = lo, hi, align
-        self.mem = np.zeros(hi - lo, np.uint8)
-        self._next = lo
-        self._allocs: Dict[int, int] = {}
-
-    def write(self, addr: int, arr: np.ndarray) -> None:
-        b = np.ascontiguousarray(arr).view(np.uint8).ravel()
-        self.mem[addr - self.lo:addr - self.lo + b.size] = b
-
-    def read(self, addr: int, nbytes: int, dtype=np.uint8, shape=None):
-        out = self.mem[addr - self.lo:addr - self.lo + nbytes] \
-            .view(dtype).copy()
-        return out.reshape(shape) if shape is not None else out
+        super().__init__(hi - lo, align)
+        self.lo, self.size, self._next = lo, hi, lo
 
 
 @dataclass(frozen=True)

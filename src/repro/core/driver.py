@@ -5,25 +5,51 @@ physically-contiguous DRAM allocator (VTABufferAlloc), typed load/store
 views for DMA, and the fetch-module control registers (§2.4: `control`,
 `insn_count`, `insns`).  On real hardware these are AXI/MMIO; here they
 drive the behavioural simulator.  Cache flush/invalidate (non-coherent
-SoCs) are modelled as no-op hooks with counters so the runtime code path
-stays faithful and testable.
+SoCs) are modelled as hooks with counters so the runtime code path
+stays faithful and testable; a flush also marks the flushed range as
+written (see :meth:`Dram.touch`).
 """
 from __future__ import annotations
 
+import hashlib
+import itertools
+import threading
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
 import numpy as np
 
+#: log2 of the page size whose write epochs a Dram keeps (4 KiB pages)
+PAGE_SHIFT = 12
+
+# write stamps, unique across every Dram of the process: a page's epoch
+# is the stamp of the last write that touched it, and clone / copy_from
+# carry the epochs with the bytes.  So two ranges whose newest epoch is
+# the same stamp hold the same bytes (both are the writer's range as it
+# was after that write, or zeros for stamp 0), and one memo of region
+# digests serves every Dram: keyed by (addr, nbytes, newest epoch),
+# shared under _DIGEST_LOCK, LRU order, bounded by entry count.
+_STAMPS = itertools.count(1)
+_DIGESTS: Dict[tuple, bytes] = {}
+_DIGEST_LOCK = threading.Lock()
+_DIGESTS_CAP = 1 << 16
+
 
 class Dram:
     """Flat byte-addressed DRAM with a bump allocator (physically contiguous
-    buffers, as required by VTA's DMA engines)."""
+    buffers, as required by VTA's DMA engines).  Every write path marks
+    the pages it changes with a fresh epoch, so :meth:`region_digest` can
+    name a range's content without reading it again."""
+
+    #: the address of ``mem[0]`` (nonzero for a window onto part of an
+    #: address space)
+    lo = 0
 
     def __init__(self, size: int = 1 << 28, align: int = 64):
         self.size = size
         self.align = align
         self.mem = np.zeros(size, dtype=np.uint8)
+        self.epochs = np.zeros(-(-size >> PAGE_SHIFT), dtype=np.int64)
         self._next = align  # keep address 0 as a null sentinel
         self._allocs: Dict[int, int] = {}
 
@@ -54,9 +80,11 @@ class Dram:
             used = (self._next + self.align - 1) // self.align * self.align
             c.size = used
             c.mem = self.mem[:used].copy()
+            c.epochs = self.epochs[:-(-used >> PAGE_SHIFT)].copy()
         else:
             c.size = self.size
             c.mem = self.mem.copy()
+            c.epochs = self.epochs.copy()
         c._next = self._next
         c._allocs = dict(self._allocs)
         return c
@@ -66,16 +94,56 @@ class Dram:
         if other.size != self.size:
             raise ValueError(f"DRAM size mismatch: {other.size} != {self.size}")
         self.mem[:] = other.mem
+        self.epochs[:] = other.epochs
         self._next = other._next
         self._allocs = dict(other._allocs)
+
+    # -- write epochs and content digests ----------------------------------
+    def touch(self, addr: int, nbytes: int) -> None:
+        """Mark ``[addr, addr + nbytes)`` as written: its pages take a
+        fresh epoch.  Every write path calls it; code that changes
+        ``mem`` directly calls it through ``Device.flush_cache``."""
+        if nbytes > 0:
+            a = addr - self.lo
+            self.epochs[a >> PAGE_SHIFT:
+                        ((a + nbytes - 1) >> PAGE_SHIFT) + 1] = next(_STAMPS)
+
+    def region_digest(self, addr: int, nbytes: int) -> bytes:
+        """SHA-1 digest of the bytes ``[addr, addr + nbytes)``: equal
+        digests mean equal bytes.  Memoized process-wide by the range
+        and its newest page epoch, so the bytes are hashed again only
+        after a write touched the range, and clones of one image hash
+        each range once between them."""
+        a = addr - self.lo
+        if a < 0 or a + nbytes > self.mem.size:
+            raise IndexError(f"DRAM range [{addr}, {addr + nbytes}) is "
+                             f"outside [{self.lo}, {self.lo + self.mem.size})")
+        stamp = int(self.epochs[a >> PAGE_SHIFT:
+                                ((a + nbytes - 1) >> PAGE_SHIFT) + 1]
+                    .max(initial=0))
+        key = (addr, nbytes, stamp)
+        with _DIGEST_LOCK:
+            d = _DIGESTS.pop(key, None)
+            if d is not None:
+                _DIGESTS[key] = d       # re-insert: LRU order by last hit
+                return d
+        d = hashlib.sha1(self.mem[a:a + nbytes]).digest()
+        with _DIGEST_LOCK:
+            while len(_DIGESTS) >= _DIGESTS_CAP:
+                _DIGESTS.pop(next(iter(_DIGESTS)))
+            _DIGESTS[key] = d
+        return d
 
     # -- typed access ---------------------------------------------------
     def write(self, addr: int, arr: np.ndarray) -> None:
         b = np.ascontiguousarray(arr).view(np.uint8).ravel()
-        self.mem[addr:addr + b.size] = b
+        a = addr - self.lo
+        self.mem[a:a + b.size] = b
+        self.touch(addr, b.size)
 
     def read(self, addr: int, nbytes: int, dtype=np.uint8, shape=None) -> np.ndarray:
-        raw = self.mem[addr:addr + nbytes]
+        a = addr - self.lo
+        raw = self.mem[a:a + nbytes]
         out = raw.view(dtype).copy()
         return out.reshape(shape) if shape is not None else out
 
@@ -152,7 +220,10 @@ class Device:
 
     # non-coherent-SoC cache maintenance hooks (§3.2)
     def flush_cache(self, addr: int, nbytes: int) -> None:
+        """The host wrote ``[addr, addr + nbytes)``: count the flush and
+        give the range's pages a fresh write epoch."""
         self.cache_flushes += 1
+        self.dram.touch(addr, nbytes)
 
     def invalidate_cache(self, addr: int, nbytes: int) -> None:
         self.cache_invalidates += 1

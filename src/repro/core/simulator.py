@@ -166,6 +166,11 @@ class RunStats:
     upload_bytes: int = 0
     wgt_bytes: int = 0
     wgt_hit_bytes: int = 0
+    # PallasBackend WGT LOADs: the DRAM bytes the stream's WGT LOADs
+    # name, and the part the host never copied out of DRAM because every
+    # operand built from it was found on the device by its source
+    wgt_load_bytes: int = 0
+    wgt_skip_bytes: int = 0
     # expert layers (Program.expert_matmul), on the segment of each
     # routed expert's stream: the call's live rows routed to that expert,
     # the rows launched for it (live rounded up to the template's batch),
@@ -208,7 +213,8 @@ class RunStats:
                       "n_buffer_fences", "staging_bytes_per_call",
                       "tiles_resolved", "tile_batches", "lut_launches",
                       "decode_evictions", "plan_hit", "upload_bytes",
-                      "wgt_bytes", "wgt_hit_bytes", "moe_rows",
+                      "wgt_bytes", "wgt_hit_bytes", "wgt_load_bytes",
+                      "wgt_skip_bytes", "moe_rows",
                       "moe_rows_padded", "moe_experts_hit",
                       "tune_cache_hits", "tune_cache_misses"):
                 setattr(out, f, getattr(out, f) + getattr(r, f))
@@ -369,10 +375,20 @@ class Simulator:
         s = spec
         self.uop_sram = np.zeros(s.uop_depth, dtype=np.uint32)
         self.inp_sram = np.zeros((s.inp_depth, s.batch, s.block_in), dtype=np.int8)
-        self.wgt_sram = np.zeros((s.wgt_depth, s.block_out, s.block_in), dtype=np.int8)
+        self._wgt_sram: Optional[np.ndarray] = None
         self.acc_sram = np.zeros((s.acc_depth, s.batch, s.block_out), dtype=np.int32)
         # out buffer mirrors acc, narrowed (write-through on compute, §2.5)
         self.out_sram = np.zeros((s.acc_depth, s.batch, s.block_out), dtype=np.int8)
+
+    @property
+    def wgt_sram(self) -> np.ndarray:
+        """The WGT scratchpad, zeroed when first used: an engine that
+        never writes or reads it never allocates it."""
+        if self._wgt_sram is None:
+            s = self.spec
+            self._wgt_sram = np.zeros((s.wgt_depth, s.block_out, s.block_in),
+                                      dtype=np.int8)
+        return self._wgt_sram
 
     # ------------------------------------------------------------------
     def run(self) -> RunStats:
@@ -511,9 +527,10 @@ class Simulator:
             self.acc_sram[np.unique(dsts)] = 0
         else:
             # acc[dst] += inp[src] @ wgt[wgt].T, int8 x int8 -> int32
+            wgt_sram = self.wgt_sram
             for d, s_, w in zip(dsts, srcs, wgts):
                 a = self.inp_sram[s_].astype(np.int32)
-                b = self.wgt_sram[w].astype(np.int32)
+                b = wgt_sram[w].astype(np.int32)
                 self.acc_sram[d] += a @ b.T
             stats.gemm_macs += (len(dsts) * self.spec.batch *
                                 self.spec.block_in * self.spec.block_out)
